@@ -308,5 +308,6 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="CI subset: 6 design points")
     args = ap.parse_args()
+    runtime.enable_compilation_cache()
     print("name,us_per_call,derived")
     bench_bank(args.out_flag or args.out, smoke=args.smoke)

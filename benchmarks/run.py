@@ -9,18 +9,27 @@ Prints ``name,us_per_call,derived`` CSV.  Sections:
 import sys
 
 
-def main() -> None:
+def main() -> int:
+    """Run every section; the exit status is 1 if any benchmark raised."""
+    from repro.kernels import runtime
+    runtime.enable_compilation_cache()
     print("name,us_per_call,derived")
     from . import paper_tables, kernel_bench, bank_bench, roofline_report
+    failed = []
     for section in (paper_tables, kernel_bench, bank_bench,
                     roofline_report):
         for fn in section.ALL:
             try:
                 fn()
             except Exception as e:      # a bench failure must not hide others
-                print(f"{section.__name__}.{fn.__name__},0.00,ERROR:{e!r}",
-                      file=sys.stdout)
+                name = f"{section.__name__}.{fn.__name__}"
+                failed.append(name)
+                print(f"{name},0.00,ERROR:{e!r}", file=sys.stdout)
+    if failed:
+        print(f"{len(failed)} benchmark(s) failed: {', '.join(failed)}",
+              file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == '__main__':
-    main()
+    sys.exit(main())

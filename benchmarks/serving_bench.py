@@ -38,6 +38,7 @@ import os
 
 from repro import designs
 from repro.core.bank import Bank
+from repro.kernels import runtime
 from repro.serving import (Autoscaler, Worker, bursty_arrivals,
                            diurnal_arrivals, poisson_arrivals, synthesize)
 
@@ -318,5 +319,6 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="CI subset: reduced load grid and request count")
     args = ap.parse_args()
+    runtime.enable_compilation_cache()
     print("name,us_per_call,derived")
     bench_serving(args.out_flag or args.out, smoke=args.smoke)

@@ -14,8 +14,8 @@ The PR-2 ``core/bank.py`` monolith is now three decoupled layers:
                        backends into bit-exact, cycle-accounted
                        execution.
   :mod:`.sharded`   -- N replicated banks over a mesh axis
-                       (``sharded_execute``) via the compat shard_map
-                       shim + launch-layer partition specs.
+                       (``sharded_execute``) via ``jax.shard_map``
+                       + launch-layer partition specs.
 
 This package is a drop-in replacement for the old module:
 ``from repro.core import bank`` and every public PR-2 name

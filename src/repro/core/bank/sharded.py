@@ -2,8 +2,8 @@
 
 The paper's Sec. V-E bank sustains a fractional throughput on one chip;
 production serving replicates that bank across devices.  This module
-runs one bank *per device slice* along a named mesh axis via the
-``repro.compat`` shard_map shim: the global batch is split evenly, every
+runs one bank *per device slice* along a named mesh axis via
+``jax.shard_map``: the global batch is split evenly, every
 device executes its shard through the same static dispatch (scheduler +
 backend resolved exactly as in :mod:`.engine`), and the results
 concatenate back bit-exactly -- each multiplication is computed by
@@ -39,7 +39,7 @@ def _sharded_fn(plan: Plan, bits_a: int, bits_b: int, backend: str,
                 scheduler: str, mesh, axis: str, local: int):
     # Lazy imports: core must stay importable without touching the
     # launch layer (and jax device state) at module-import time.
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.launch.sharding import bank_batch_spec
 
     bank = Bank(plan, bits_a, bits_b, backend=backend, scheduler=scheduler)

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import limbs as L
+from repro.kernels.mcim_fold.kernel import carry_normalize, ppm_columns
 
 
 def _bank_kernel(tbl_ref, a_ref, b_ref, out_ref, acc_ref, *,
@@ -61,26 +61,12 @@ def _bank_kernel(tbl_ref, a_ref, b_ref, out_ref, acc_ref, *,
     bm = b * mask
 
     # ---- PPM + compressor: masked column sums, carries deferred ---------
-    # Static loop over B limbs; each iteration is one vector multiply
-    # over the row tile (one "row" of the shared hardware PPM array).
-    acc = acc_ref[...]
-    for jj in range(lb):
-        p = a * bm[:, jj:jj + 1]                          # exact 16x16 in u32
-        acc = acc.at[:, jj:jj + la].add(p & L.MASK)
-        acc = acc.at[:, jj + 1:jj + la + 1].add(p >> L.RADIX_BITS)
-    acc_ref[...] = acc
+    acc_ref[...] = acc_ref[...] + ppm_columns(a, bm, la + lb)
 
     # ---- last step: single final-adder pass retires the product ---------
     @pl.when(j == max_steps - 1)
     def _finish():
-        cols = acc_ref[...]
-        carry = jnp.zeros((a.shape[0],), jnp.uint32)
-        norm = []
-        for k in range(la + lb):
-            tot = cols[:, k] + carry
-            norm.append(tot & L.MASK)
-            carry = tot >> L.RADIX_BITS
-        out_ref[0] = jnp.stack(norm, axis=1)
+        out_ref[0] = carry_normalize(acc_ref[...], la + lb)
 
 
 @functools.partial(jax.jit,

@@ -90,57 +90,73 @@ def fold_geometry(la: int, lb: int, ct: int,
                         out_width=la + lb)
 
 
+def place(x, shift: int, width: int):
+    """``x``'s columns moved up by ``shift`` inside ``width`` columns.
+
+    A static pad, so placing partial products is a full-width vector add:
+    the Pallas TPU lowering has no scatter-add (``.at[...].add``).
+    """
+    return jnp.pad(x, ((0, 0), (shift, width - shift - x.shape[1])))
+
+
+def ppm_columns(a, b, width: int):
+    """PPM + compressor: carry-save column sums of ``a * b``.
+
+    One exact 16x16->32 lane product per B limb over the whole tile (one
+    "row" of the hardware PPM array); its low and high halves land at
+    columns ``jj`` and ``jj + 1``.  Carries stay deferred.
+    """
+    cols = None
+    for jj in range(b.shape[1]):
+        p = a * b[:, jj:jj + 1]
+        row = (place(p & L.MASK, jj, width)
+               + place(p >> L.RADIX_BITS, jj + 1, width))
+        cols = row if cols is None else cols + row
+    return cols
+
+
+def carry_normalize(cols, out_limbs: int):
+    """Final adder: ``out_limbs`` canonical limbs out of column sums."""
+    carry = jnp.zeros((cols.shape[0],), jnp.uint32)
+    outs = []
+    for k in range(out_limbs):
+        tot = (cols[:, k] if k < cols.shape[1]
+               else jnp.zeros_like(carry)) + carry
+        outs.append(tot & L.MASK)
+        carry = tot >> L.RADIX_BITS
+    return jnp.stack(outs, axis=1)
+
+
 def _fb_kernel(a_ref, b_ref, out_ref, acc_ref, *, la, lb, ct, chunk):
     """One grid step = one MCIM clock cycle for a tile of multiplications."""
     j = pl.program_id(1)                       # cycle index within CT
     width = la + chunk + 1                     # M + N/CT (+carry) window
+    out_w = la + lb
 
     a = a_ref[...]                             # (TB, LA) canonical limbs
-    b = b_ref[...]                             # (TB, CHUNK) this cycle's chunk
+    b = b_ref[0]                               # (TB, CHUNK) this cycle's chunk
 
-    # ---- feedback shift: acc <- acc >> CHUNK limbs (cycle 0: acc = 0) ----
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(j != 0)
-    def _shift():
-        shifted = jnp.concatenate(
-            [acc_ref[:, chunk:],
-             jnp.zeros((a.shape[0], chunk), jnp.uint32)], axis=1)
-        acc_ref[...] = shifted
+    # ---- PPM + compressor into the fed-back window, then the 1CA --------
+    normalized = carry_normalize(acc_ref[...] + ppm_columns(a, b, width),
+                                 width)
+    # ---- feedback register: the window shifted down by CHUNK limbs ------
+    acc_ref[...] = place(normalized[:, chunk:], 0, width)
 
-    # ---- PPM + compressor: column sums, carries deferred ----------------
-    # Static loop over the chunk's limbs; every iteration is one vector
-    # multiply over the batch tile (the "row" of the hardware PPM array).
-    acc = acc_ref[...]
-    for jj in range(chunk):
-        p = a * b[:, jj:jj + 1]                           # exact 16x16 in u32
-        lo = p & L.MASK
-        hi = p >> L.RADIX_BITS
-        acc = acc.at[:, jj:jj + la].add(lo)
-        acc = acc.at[:, jj + 1:jj + la + 1].add(hi)
+    # ---- retire CHUNK low limbs at t*CHUNK; the last cycle retires every
+    # remaining high limb.  One branch per cycle keeps the lane offsets
+    # static (the TPU lowering refuses dynamic lane-offset stores).
+    for t in range(ct):
+        lo = t * chunk
+        n = chunk if t < ct - 1 else out_w - lo
 
-    # ---- final adder (1CA): carry-propagate the M+N/CT window -----------
-    carry = jnp.zeros((a.shape[0],), jnp.uint32)
-    norm = []
-    for k in range(width):
-        tot = acc[:, k] + carry
-        norm.append(tot & L.MASK)
-        carry = tot >> L.RADIX_BITS
-    normalized = jnp.stack(norm, axis=1)
-    acc_ref[...] = normalized
-
-    # ---- retire CHUNK low limbs into the output tile ---------------------
-    out_ref[:, pl.dslice(j * chunk, chunk)] = normalized[:, :chunk]
-
-    # ---- last cycle: the remaining high limbs complete the product -------
-    @pl.when(j == ct - 1)
-    def _tail():
-        tail_limbs = la + lb - ct * chunk            # may be < la+1 (padding)
-        if tail_limbs > 0:
-            out_ref[:, pl.dslice(ct * chunk, tail_limbs)] = \
-                normalized[:, chunk:chunk + tail_limbs]
+        @pl.when(j == t)
+        def _retire(lo=lo, n=n):
+            out_ref[...] = out_ref[...] + place(normalized[:, :n], lo, out_w)
 
 
 def _ff_kernel(a_ref, b_ref, out_ref, acc_ref, *, la, lb, ct, chunk):
@@ -158,46 +174,26 @@ def _ff_kernel(a_ref, b_ref, out_ref, acc_ref, *, la, lb, ct, chunk):
     width = la + ct * chunk + 1
 
     a = a_ref[...]                             # (TB, LA) canonical limbs
-    b = b_ref[...]                             # (TB, CHUNK) this cycle's chunk
+    b = b_ref[0]                               # (TB, CHUNK) this cycle's chunk
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # ---- shared PPM: carry-save columns of a * b_chunk ------------------
-    cols = jnp.zeros((a.shape[0], la + chunk + 1), jnp.uint32)
-    for jj in range(chunk):
-        p = a * b[:, jj:jj + 1]                           # exact 16x16 in u32
-        cols = cols.at[:, jj:jj + la].add(p & L.MASK)
-        cols = cols.at[:, jj + 1:jj + la + 1].add(p >> L.RADIX_BITS)
+    cols = ppm_columns(a, b, la + chunk + 1)
 
     # ---- 2*CT:2 compressor: add into the register file at j*chunk -------
-    window = acc_ref[:, pl.dslice(j * chunk, la + chunk + 1)]
-    acc_ref[:, pl.dslice(j * chunk, la + chunk + 1)] = window + cols
+    # (one branch per cycle: static lane offsets, see _fb_kernel)
+    for t in range(ct):
+        @pl.when(j == t)
+        def _compress(t=t):
+            acc_ref[...] = acc_ref[...] + place(cols, t * chunk, width)
 
     # ---- last cycle: single final-adder pass over the full width --------
     @pl.when(j == ct - 1)
     def _finish():
-        acc = acc_ref[...]
-        carry = jnp.zeros((a.shape[0],), jnp.uint32)
-        norm = []
-        for k in range(la + lb):
-            tot = (acc[:, k] if k < width else jnp.zeros_like(carry)) + carry
-            norm.append(tot & L.MASK)
-            carry = tot >> L.RADIX_BITS
-        out_ref[...] = jnp.stack(norm, axis=1)
-
-
-def _kara_carry(cols, out_limbs):
-    """Carry-propagate ``out_limbs`` canonical limbs out of column sums."""
-    carry = jnp.zeros((cols.shape[0],), jnp.uint32)
-    outs = []
-    for k in range(out_limbs):
-        tot = (cols[:, k] if k < cols.shape[1]
-               else jnp.zeros_like(carry)) + carry
-        outs.append(tot & L.MASK)
-        carry = tot >> L.RADIX_BITS
-    return jnp.stack(outs, axis=1)
+        out_ref[...] = carry_normalize(acc_ref[...], la + lb)
 
 
 def _kara_kernel(a_ref, b_ref, out_ref, acc_ref, *, la, lb, n, half):
@@ -223,55 +219,42 @@ def _kara_kernel(a_ref, b_ref, out_ref, acc_ref, *, la, lb, n, half):
     a = a_ref[...]                             # (TB, n) padded canonical limbs
     b = b_ref[...]
     tb = a.shape[0]
-    zero_col = jnp.zeros((tb, 1), jnp.uint32)
 
     a0, a1 = a[:, :half], a[:, half:]
     b0, b1 = b[:, :half], b[:, half:]
-    sa = _kara_carry(a0 + a1, hp)              # A0+A1, normalized to hp limbs
-    sb = _kara_carry(b0 + b1, hp)
-    a0p = jnp.concatenate([a0, zero_col], axis=1)
-    a1p = jnp.concatenate([a1, zero_col], axis=1)
-    b0p = jnp.concatenate([b0, zero_col], axis=1)
-    b1p = jnp.concatenate([b1, zero_col], axis=1)
+    sa = carry_normalize(a0 + a1, hp)          # A0+A1, normalized to hp limbs
+    sb = carry_normalize(b0 + b1, hp)
 
     # this cycle's operands for the ONE shared PPM
-    av = jnp.where(j == 0, a0p, jnp.where(j == 1, a1p, sa))
-    bv = jnp.where(j == 0, b0p, jnp.where(j == 1, b1p, sb))
+    av = jnp.where(j == 0, place(a0, 0, hp),
+                   jnp.where(j == 1, place(a1, 0, hp), sa))
+    bv = jnp.where(j == 0, place(b0, 0, hp),
+                   jnp.where(j == 1, place(b1, 0, hp), sb))
 
     # shared PPM + its 1CA: T_j normalized to 2*hp canonical limbs
-    cols = jnp.zeros((tb, 2 * hp), jnp.uint32)
-    for jj in range(hp):
-        p = av * bv[:, jj:jj + 1]                         # exact 16x16 in u32
-        cols = cols.at[:, jj:jj + hp].add(p & L.MASK)
-        cols = cols.at[:, jj + 1:jj + hp + 1].add(p >> L.RADIX_BITS)
-    t = _kara_carry(cols, 2 * hp)
+    t = carry_normalize(ppm_columns(av, bv, 2 * hp), 2 * hp)
 
-    def place(shift):
-        # jnp.pad, not .at[].add: a full-width scatter would close over an
-        # empty index constant, which pallas_call rejects
-        take = min(2 * hp, width - shift)
-        return jnp.pad(t[:, :take], ((0, 0), (shift, width - shift - take)))
+    def put(shift):
+        return place(t[:, :min(2 * hp, width - shift)], shift, width)
 
-    def neg_place(shift):
-        # NOT+1 two's complement of (T_j << shift) mod 2**(16*width);
-        # the +1 is returned as a separate column-0 increment
-        inv = jnp.full((tb, width), jnp.uint32(L.MASK)) - place(shift)
-        return inv.at[:, 0].add(1)
+    def neg_put(shift):
+        # NOT+1 two's complement of (T_j << shift) mod 2**(16*width)
+        inv = jnp.full((tb, width), jnp.uint32(L.MASK)) - put(shift)
+        return inv + place(jnp.ones((tb, 1), jnp.uint32), 0, width)
 
     # compressor feedback: accumulate this cycle's placed terms
     @pl.when(j == 0)
     def _t0():                                 # +T0<<0  -T0<<h
-        acc_ref[...] = place(0) + neg_place(half)
+        acc_ref[...] = put(0) + neg_put(half)
 
     @pl.when(j == 1)
     def _t1():                                 # +T1<<2h -T1<<h
-        acc_ref[...] = acc_ref[...] + place(2 * half) + neg_place(half)
+        acc_ref[...] = acc_ref[...] + put(2 * half) + neg_put(half)
 
     # last cycle: +T2<<h, then the single final-adder pass
     @pl.when(j == 2)
     def _t2():
-        acc = acc_ref[...] + place(half)
-        out_ref[...] = _kara_carry(acc, la + lb)
+        out_ref[...] = carry_normalize(acc_ref[...] + put(half), la + lb)
 
 
 def _kara_fold_call(a, b, tile_b, interpret):
@@ -330,7 +313,10 @@ def mcim_fold_mul(a: jax.Array, b: jax.Array, *, ct: int = 2,
     lb = b.shape[-1]
     geo = fold_geometry(la, lb, ct, schedule)
     chunk, ct_run = geo.chunk, geo.ct_run
+    # chunk-major B, (CT, B, CHUNK): each cycle's block is one whole
+    # (tile, CHUNK) slab, a lane-legal TPU block for any CHUNK
     b = jnp.pad(b, ((0, 0), (0, chunk * ct_run - lb)))
+    b = b.reshape(bsz, ct_run, chunk).transpose(1, 0, 2)
     tile_b = min(tile_b, bsz)
     if bsz % tile_b:
         raise ValueError(f"batch {bsz} not divisible by tile {tile_b}")
@@ -342,7 +328,7 @@ def mcim_fold_mul(a: jax.Array, b: jax.Array, *, ct: int = 2,
         grid=(bsz // tile_b, ct_run),
         in_specs=[
             pl.BlockSpec((tile_b, la), lambda i, j: (i, 0)),
-            pl.BlockSpec((tile_b, chunk), lambda i, j: (i, j)),
+            pl.BlockSpec((1, tile_b, chunk), lambda i, j: (j, i, 0)),
         ],
         out_specs=pl.BlockSpec((tile_b, geo.out_width), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, geo.out_width), jnp.uint32),

@@ -131,10 +131,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        # jax < 0.4.31 returns a one-element list of dicts; later versions
-        # return the dict directly.
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
 
     # Scan-aware accounting from the compiled artifact (hlo_cost): XLA's

@@ -209,7 +209,7 @@ def flash_attention_context_parallel(
     The production layout for archs whose head count cannot use the
     model axis (gemma3/paligemma kv=1, 4-8 q heads).
     """
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = mesh.axis_names

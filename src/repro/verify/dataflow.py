@@ -563,7 +563,7 @@ class _Interp:
         closed = branches[idx]
         return self.run_jaxpr(closed.jaxpr, closed.consts, ops)
 
-    def _p_pjit(self, eqn, vals):
+    def _p_jit(self, eqn, vals):
         closed = eqn.params["jaxpr"]
         return self.run_jaxpr(closed.jaxpr, closed.consts, vals)
 
@@ -574,11 +574,17 @@ class _Interp:
     # -- state primitives -----------------------------------------------
     def _decode_indexer(self, tree, leaves, ref):
         import jax.tree_util as jtu
-        indexers = jtu.tree_unflatten(tree, list(leaves))
-        if len(indexers) != 1:
-            raise AnalyzerGap("stacked ref indexers not modeled")
+        from jax._src.state.indexing import NDIndexer
+        # a ref access carries a tuple of transforms: none is the whole
+        # ref (``ref[...]``), one NDIndexer a sliced/indexed region
+        transforms = jtu.tree_unflatten(tree, list(leaves))
+        if not transforms:
+            return tuple(slice(0, n, 1) for n in ref.shape)
+        if len(transforms) != 1 or not isinstance(transforms[0],
+                                                  NDIndexer):
+            raise AnalyzerGap(f"ref transforms {transforms} not modeled")
         region = []
-        for entry in indexers[0].indices:
+        for entry in transforms[0].indices:
             if hasattr(entry, "start") and hasattr(entry, "size"):
                 start, size = entry.start, entry.size
                 stride = getattr(entry, "stride", 1)
@@ -662,6 +668,20 @@ class LaunchReport:
         d["violations"] = [dataclasses.asdict(v)
                            for v in self.violations]
         return d
+
+
+def _block_dims(bm) -> tuple:
+    """Per-dimension block sizes of a BlockMapping (squeezed dims: 1)."""
+    from jax.experimental import pallas as pl
+    dims = []
+    for b in bm.block_shape:
+        if isinstance(b, pl.Blocked):
+            dims.append(int(b.block_size))
+        elif isinstance(b, pl.Squeezed):
+            dims.append(1)
+        else:
+            raise AnalyzerGap(f"block dimension {b!r} not modeled")
+    return tuple(dims)
 
 
 def _eval_index_map(interp, closed, args):
@@ -756,10 +776,8 @@ def analyze_contract(contract, budget=None):
     per_map_indices = []
     try:
         for bm in block_mappings:
-            bs = tuple(bm.block_shape)
-            if not all(isinstance(b, (int, np.integer)) for b in bs):
-                raise AnalyzerGap(f"block shape {bs} not static")
-            arr_shape = tuple(bm.array_shape_dtype.shape)
+            bs = _block_dims(bm)
+            arr_shape = tuple(bm.array_aval.shape)
             nblocks = tuple(-(-a // b) for a, b in zip(arr_shape, bs))
             seq = []
             for s in steps:
@@ -859,9 +877,8 @@ def analyze_contract(contract, budget=None):
     hbm = breakdown.smem_bytes                 # table prefetched once
     for mi, seq in enumerate(per_map_indices):
         bm = block_mappings[mi]
-        bs = tuple(bm.block_shape)
-        blk_bytes = int(np.prod(bs)) * np.dtype(
-            bm.array_shape_dtype.dtype).itemsize
+        blk_bytes = int(np.prod(_block_dims(bm))) * np.dtype(
+            bm.array_aval.dtype).itemsize
         transfers = sum(1 for t in range(len(seq))
                         if t == 0 or seq[t] != seq[t - 1])
         hbm += blk_bytes * transfers

@@ -155,7 +155,7 @@ def adder_bounds(cols, out_limbs: int, ctx: _Ctx, where: str) -> list:
     Threads the worst-case carry through the column walk and checks the
     uint32 expression ``tot = col + carry`` at every position -- the
     overflow surface of final_adder_1ca/_3ca, the kernels' unrolled
-    carry loops, and _kara_carry alike.  Returns canonical bounds.
+    carry loops, and carry_normalize alike.  Returns canonical bounds.
     """
     carry = 0
     width = len(cols)
@@ -290,7 +290,7 @@ def _kara_kernel_walk(amax, bmax, ctx):
     pad = lambda x: x + [0] * (n - len(x))
     a0, a1 = pad(amax)[:half], pad(amax)[half:]
     b0, b1 = pad(bmax)[:half], pad(bmax)[half:]
-    # _kara_carry(a0 + a1, hp): raw column sums then carry walk
+    # carry_normalize(a0 + a1, hp): raw column sums then carry walk
     sums_a = [x + y for x, y in zip(a0, a1)]
     sums_b = [x + y for x, y in zip(b0, b1)]
     ctx.check(sums_a, "kara kernel A0+A1 columns")

@@ -3,7 +3,7 @@
 Both ``launch.roofline.count_pallas_launches`` (the dispatch-tax metric)
 and ``verify.dataflow`` (the static hazard/bounds/roofline analyzer)
 need to find equations inside arbitrarily nested jaxprs: a jitted call
-site wraps the program in a ``pjit`` equation whose body is a
+site wraps the program in a ``jit`` equation whose body is a
 ClosedJaxpr, ``lax.cond`` branches are ClosedJaxprs, ``scatter-add``
 carries a raw update Jaxpr, and ``pallas_call`` holds the kernel body
 as a raw Jaxpr.  The traversal rules for all of those live here, in
@@ -33,7 +33,7 @@ def subjaxprs(eqn):
 def walk(jaxpr, into_pallas: bool = False):
     """Yield every equation in ``jaxpr`` and its nested jaxprs.
 
-    Descends through pjit / closed-call / cond / scan bodies; kernel
+    Descends through jit / closed-call / cond / scan bodies; kernel
     jaxprs inside ``pallas_call`` equations are skipped unless
     ``into_pallas`` (the host-program and kernel-body instruction
     streams are different machines and almost every analysis wants

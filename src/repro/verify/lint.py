@@ -33,9 +33,8 @@ Rules:
                       attributes -- per-call state breaks the static
                       (cts, n_ops) -> assignment contract the bank's
                       jitted dispatch relies on
-``interpret-env``     reading the ``REPRO_INTERPRET`` /
-                      ``REPRO_PALLAS_INTERPRET`` environment variables
-                      anywhere but ``kernels/runtime.py`` -- the one
+``interpret-env``     reading the ``REPRO_INTERPRET`` environment
+                      variable anywhere but ``kernels/runtime.py`` -- the one
                       shim that owns interpret-mode resolution; a
                       second reader can disagree with it mid-process
                       and silently mix compiled and interpreted
@@ -288,7 +287,7 @@ def _scheduler_state_writes(tree: ast.Module, path: str) -> list:
 
 
 #: interpret-mode env vars only ``kernels/runtime.py`` may read
-_INTERPRET_ENV = frozenset({"REPRO_INTERPRET", "REPRO_PALLAS_INTERPRET"})
+_INTERPRET_ENV = frozenset({"REPRO_INTERPRET"})
 
 
 def _reads_environ(node: ast.expr) -> str:

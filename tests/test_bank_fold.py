@@ -268,14 +268,9 @@ def test_runtime_flag_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_INTERPRET", "1")
     runtime.reset()
     assert runtime.interpret_mode() is True
-    # legacy name still honored when the new one is unset
-    monkeypatch.delenv("REPRO_INTERPRET")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "off")
-    runtime.reset()
-    assert runtime.interpret_mode() is False
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
-    runtime.reset()
     # auto: interpret on the CPU container
+    monkeypatch.delenv("REPRO_INTERPRET")
+    runtime.reset()
     assert runtime.interpret_mode() is True
     runtime.reset()
 

@@ -53,7 +53,7 @@ def test_pinned_jax_hlo_dialect_parses():
 
 
 def test_pinned_jax_hlo_dialect_parses_chained_dots():
-    """Second dialect probe (re-validated at the 0.4.37 pin): chained
+    """Second dialect probe (re-validated at the 0.9.0 pin): chained
     contractions must each be found -- a parser that silently drops
     every dot but the first would still pass the single-dot probe."""
     from repro.launch import hlo_cost

@@ -29,7 +29,7 @@ np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(tree["w"]))
 print("OK elastic-reshard")
 
 # ---------------- exact psum inside shard_map ----------------
-from repro.compat import shard_map
+from jax import shard_map
 from repro.exact import exact_psum
 
 dmesh = jax.make_mesh((4,), ("data",))
