@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from .. import limbs as L
 from ..mcim import MCIMConfig
 from ..planner import Plan
+from repro.spans import span
 from .backends import BACKENDS, cached_mul, get_backend
 from .schedule import (completion_cycles, get_scheduler,
                        histogram_percentile, latency_histogram)
@@ -155,7 +156,8 @@ class Bank:
                 "fused backend needs uniform signedness across instances "
                 "(the correction pass is applied bank-wide)")
         self._signed = self.instances[0].signed
-        self._compiled = {}           # batch size -> jitted execute
+        # batch size -> (jitted execute, args of its launch span)
+        self._compiled = {}
         self.last_report = None
 
     # -------------------------------------------------------------- reports
@@ -163,30 +165,34 @@ class Bank:
         """Cycle accounting for one batch.  ``scheduler`` overrides the
         bank's policy for this report only (e.g. a StreamingScheduler
         carrying a recorded arrival trace) without recompiling dispatch."""
-        sched = self.scheduler if scheduler is None else \
-            get_scheduler(scheduler)
-        assign, cycles = sched.schedule(self._cts, batch)
-        insts = tuple(
-            InstanceReport(cfg, len(ops), len(ops) * cfg.ct)
-            for cfg, ops in zip(self.instances, assign))
-        # per-request latency: completion minus admission, where
-        # admission is the policy's own arrival trace (cycle 0 for the
-        # batch policies).  Arrival-aware policies expose arrivals_for.
-        arrivals = sched.arrivals_for(batch) \
-            if hasattr(sched, "arrivals_for") else (0,) * batch
-        finish = completion_cycles(self._cts, assign, arrivals)
-        hist = latency_histogram(f - a for f, a in zip(finish, arrivals))
-        footprints = tuple(
-            be.working_set(cfg, self.la, self.lb, self.tile_b)
-            for cfg, be in zip(self.instances, self._backends))
-        # fused instances time-share ONE datapath, so the bank's working
-        # set is the largest instance footprint, not the sum
-        ws = max(footprints) if self.backend == "fused" else sum(footprints)
-        return BankReport(batch=batch, cycles=cycles, instances=insts,
-                          plan_throughput=self.plan.throughput,
-                          working_set_bytes=ws,
-                          scheduler=sched.name,
-                          latency_hist=hist)
+        with span("bank.report"):
+            sched = self.scheduler if scheduler is None else \
+                get_scheduler(scheduler)
+            assign, cycles = sched.schedule(self._cts, batch)
+            insts = tuple(
+                InstanceReport(cfg, len(ops), len(ops) * cfg.ct)
+                for cfg, ops in zip(self.instances, assign))
+            # per-request latency: completion minus admission, where
+            # admission is the policy's own arrival trace (cycle 0 for
+            # the batch policies).  Arrival-aware policies expose
+            # arrivals_for.
+            arrivals = sched.arrivals_for(batch) \
+                if hasattr(sched, "arrivals_for") else (0,) * batch
+            finish = completion_cycles(self._cts, assign, arrivals)
+            hist = latency_histogram(
+                f - a for f, a in zip(finish, arrivals))
+            footprints = tuple(
+                be.working_set(cfg, self.la, self.lb, self.tile_b)
+                for cfg, be in zip(self.instances, self._backends))
+            # fused instances time-share ONE datapath, so the bank's
+            # working set is the largest instance footprint, not the sum
+            ws = max(footprints) if self.backend == "fused" \
+                else sum(footprints)
+            return BankReport(batch=batch, cycles=cycles, instances=insts,
+                              plan_throughput=self.plan.throughput,
+                              working_set_bytes=ws,
+                              scheduler=sched.name,
+                              latency_hist=hist)
 
     # -------------------------------------------------------------- execute
     def dispatch_fn(self, batch: int):
@@ -217,7 +223,14 @@ class Bank:
         return run
 
     def _build(self, batch: int):
-        return jax.jit(self.dispatch_fn(batch))
+        """The jitted dispatch for ``batch`` and the args of its launch
+        span: the rows given and, on the fused backend, the rows the
+        kernel computes."""
+        run = self.dispatch_fn(batch)
+        args = {"rows": batch}
+        if hasattr(run, "kernel_rows"):
+            args["kernel_rows"] = run.kernel_rows
+        return jax.jit(run), args
 
     def launch_count(self, batch: int) -> int:
         """Pallas launches one bank round issues for this batch size.
@@ -245,13 +258,15 @@ class Bank:
             raise ValueError(
                 f"operand limbs {a.shape[-1]}x{b.shape[-1]} do not match "
                 f"bank widths {self.la}x{self.lb}")
-        fn = self._compiled.get(batch)
-        if fn is None:
+        entry = self._compiled.get(batch)
+        if entry is None:
             if len(self._compiled) >= self.MAX_COMPILED:
                 self._compiled.pop(next(iter(self._compiled)))
-            fn = self._compiled[batch] = self._build(batch)
+            entry = self._compiled[batch] = self._build(batch)
         self.last_report = self.report(batch)
-        return fn(a, b)
+        fn, args = entry
+        with span("bank.launch", **args):
+            return fn(a, b)
 
     def describe(self) -> str:
         return (f"Bank[{self.plan.describe()}  backend={self.backend}  "

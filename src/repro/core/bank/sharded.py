@@ -23,6 +23,7 @@ import jax
 
 from .. import limbs as L
 from ..planner import Plan
+from repro.spans import span
 from .engine import Bank, BankReport
 
 
@@ -44,10 +45,14 @@ def _sharded_fn(plan: Plan, bits_a: int, bits_b: int, backend: str,
 
     bank = Bank(plan, bits_a, bits_b, backend=backend, scheduler=scheduler)
     run = bank.dispatch_fn(local)
-    spec = bank_batch_spec(mesh, axis, 2, local * mesh.shape[axis])
+    shards = mesh.shape[axis]
+    spec = bank_batch_spec(mesh, axis, 2, local * shards)
     fn = shard_map(run, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
                    check_vma=False)
-    return jax.jit(fn)
+    args = {"rows": local * shards}
+    if hasattr(run, "kernel_rows"):
+        args["kernel_rows"] = run.kernel_rows * shards
+    return jax.jit(fn), args
 
 
 def sharded_execute(plan: Plan, a: jax.Array, b: jax.Array, mesh,
@@ -67,10 +72,11 @@ def sharded_execute(plan: Plan, a: jax.Array, b: jax.Array, mesh,
         raise ValueError(
             f"batch mismatch: a has {a.shape[0]} ops, b has {b.shape[0]}")
     local = _local_batch(a.shape[0], mesh, axis)
-    fn = _sharded_fn(plan, a.shape[-1] * L.RADIX_BITS,
-                     b.shape[-1] * L.RADIX_BITS, backend,
-                     scheduler, mesh, axis, local)
-    return fn(a, b)
+    fn, args = _sharded_fn(plan, a.shape[-1] * L.RADIX_BITS,
+                           b.shape[-1] * L.RADIX_BITS, backend,
+                           scheduler, mesh, axis, local)
+    with span("bank.launch", **args):
+        return fn(a, b)
 
 
 def sharded_report(plan: Plan, batch: int, bits_a: int, bits_b: int,

@@ -28,6 +28,7 @@ from repro.core.mcim import MCIMConfig
 from repro.core import area_model
 from repro.core import power_model
 from repro import verify
+from repro.spans import span
 
 from .spec import DesignSpec, DesignError, TimingError, LatencyError
 
@@ -97,15 +98,16 @@ class CompiledDesign:
         Routes to the replicated sharded engine when the spec asked for
         replicas, else to the single bank's jitted dispatch.
         """
-        if isinstance(a, (int, np.integer)) and isinstance(b, (int,
-                                                               np.integer)):
-            return self._mul_ints(int(a), int(b))
-        if self.mesh is not None:
-            return sharded_execute(self.plan, a, b, self.mesh,
-                                   self.spec.mesh_axis,
-                                   backend=self.bank.backend,
-                                   scheduler=self.spec.scheduler)
-        return self.bank.execute(a, b)
+        with span("mul"):
+            if isinstance(a, (int, np.integer)) and \
+                    isinstance(b, (int, np.integer)):
+                return self._mul_ints(int(a), int(b))
+            if self.mesh is not None:
+                return sharded_execute(self.plan, a, b, self.mesh,
+                                       self.spec.mesh_axis,
+                                       backend=self.bank.backend,
+                                       scheduler=self.spec.scheduler)
+            return self.bank.execute(a, b)
 
     def _mul_ints(self, a: int, b: int) -> int:
         enc_a = self._encode(a, self.spec.bits_a, self.la)

@@ -102,6 +102,8 @@ def make_fused_dispatch(assign, configs, la: int, lb: int, batch: int, *,
     of op indices into the batch), ``configs`` the flat instance list
     aligned with it.  The returned closure maps ``(B, LA) x (B, LB) ->
     (B, LA+LB)`` limb products, bit-exact vs the per-instance path.
+    Its ``kernel_rows`` attribute is the ``N_INST x R`` rows the kernel
+    computes, padding included.
     """
     sg = super_geometry(configs, la, lb)
     n_inst = sg.n_instances
@@ -139,4 +141,5 @@ def make_fused_dispatch(assign, configs, la: int, lb: int, batch: int, *,
             out = signed_correction(a, b, out)
         return out
 
+    run.kernel_rows = n_inst * rows
     return run
