@@ -12,9 +12,9 @@ The resulting engine is
     multiplier (pure-jnp ``mcim_mul`` or a Pallas kernel), so the
     reassembled batch equals the Python-int oracle regardless of policy;
   * cycle-accounted: the dispatch schedule is simulated once per batch
-    size (and cached), giving per-instance busy cycles and the bank
-    makespan, so measured throughput can be checked against
-    ``Plan.throughput``;
+    size, and ``execute`` keeps its report beside the compiled dispatch,
+    giving per-instance busy cycles and the bank makespan, so measured
+    throughput can be checked against ``Plan.throughput``;
   * jit/pjit-compatible: the schedule is static for a given batch size,
     so ``execute`` lowers to gathers + batched multiplies + scatters
     (and :mod:`.sharded` can replicate it across a mesh axis).
@@ -156,7 +156,7 @@ class Bank:
                 "fused backend needs uniform signedness across instances "
                 "(the correction pass is applied bank-wide)")
         self._signed = self.instances[0].signed
-        # batch size -> (jitted execute, args of its launch span)
+        # batch size -> (jitted execute, args of its launch span, report)
         self._compiled = {}
         self.last_report = None
 
@@ -169,30 +169,33 @@ class Bank:
             sched = self.scheduler if scheduler is None else \
                 get_scheduler(scheduler)
             assign, cycles = sched.schedule(self._cts, batch)
-            insts = tuple(
-                InstanceReport(cfg, len(ops), len(ops) * cfg.ct)
-                for cfg, ops in zip(self.instances, assign))
-            # per-request latency: completion minus admission, where
-            # admission is the policy's own arrival trace (cycle 0 for
-            # the batch policies).  Arrival-aware policies expose
-            # arrivals_for.
-            arrivals = sched.arrivals_for(batch) \
-                if hasattr(sched, "arrivals_for") else (0,) * batch
-            finish = completion_cycles(self._cts, assign, arrivals)
-            hist = latency_histogram(
-                f - a for f, a in zip(finish, arrivals))
-            footprints = tuple(
-                be.working_set(cfg, self.la, self.lb, self.tile_b)
-                for cfg, be in zip(self.instances, self._backends))
-            # fused instances time-share ONE datapath, so the bank's
-            # working set is the largest instance footprint, not the sum
-            ws = max(footprints) if self.backend == "fused" \
-                else sum(footprints)
-            return BankReport(batch=batch, cycles=cycles, instances=insts,
-                              plan_throughput=self.plan.throughput,
-                              working_set_bytes=ws,
-                              scheduler=sched.name,
-                              latency_hist=hist)
+            return self._report(sched, batch, assign, cycles)
+
+    def _report(self, sched, batch: int, assign: tuple,
+                cycles: int) -> BankReport:
+        """The report of ``batch`` ops dispatched by ``sched`` as
+        ``assign``, retiring the last on cycle ``cycles``."""
+        insts = tuple(
+            InstanceReport(cfg, len(ops), len(ops) * cfg.ct)
+            for cfg, ops in zip(self.instances, assign))
+        # per-request latency: completion minus admission, where
+        # admission is the policy's own arrival trace (cycle 0 for the
+        # batch policies).  Arrival-aware policies expose arrivals_for.
+        arrivals = sched.arrivals_for(batch) \
+            if hasattr(sched, "arrivals_for") else (0,) * batch
+        finish = completion_cycles(self._cts, assign, arrivals)
+        hist = latency_histogram(f - a for f, a in zip(finish, arrivals))
+        footprints = tuple(
+            be.working_set(cfg, self.la, self.lb, self.tile_b)
+            for cfg, be in zip(self.instances, self._backends))
+        # fused instances time-share ONE datapath, so the bank's working
+        # set is the largest instance footprint, not the sum
+        ws = max(footprints) if self.backend == "fused" \
+            else sum(footprints)
+        return BankReport(batch=batch, cycles=cycles, instances=insts,
+                          plan_throughput=self.plan.throughput,
+                          working_set_bytes=ws, scheduler=sched.name,
+                          latency_hist=hist)
 
     # -------------------------------------------------------------- execute
     def dispatch_fn(self, batch: int):
@@ -202,6 +205,10 @@ class Bank:
         wraps it in ``jax.jit``.
         """
         assign, _ = self.scheduler.schedule(self._cts, batch)
+        return self._dispatch(assign, batch)
+
+    def _dispatch(self, assign: tuple, batch: int):
+        """The dispatch closure that runs ``batch`` ops as ``assign``."""
         if self.backend == "fused":
             from repro.kernels.bank_fold import make_fused_dispatch
             return make_fused_dispatch(assign, self.instances,
@@ -223,14 +230,18 @@ class Bank:
         return run
 
     def _build(self, batch: int):
-        """The jitted dispatch for ``batch`` and the args of its launch
-        span: the rows given and, on the fused backend, the rows the
-        kernel computes."""
-        run = self.dispatch_fn(batch)
+        """The jitted dispatch for ``batch``, the args of its launch span
+        (the rows given and, on the fused backend, the rows the kernel
+        computes) and the report of the same assignment.  Built once per
+        batch size, so its ``bank.report`` span marks a cache miss."""
+        with span("bank.report"):
+            assign, cycles = self.scheduler.schedule(self._cts, batch)
+            report = self._report(self.scheduler, batch, assign, cycles)
+        run = self._dispatch(assign, batch)
         args = {"rows": batch}
         if hasattr(run, "kernel_rows"):
             args["kernel_rows"] = run.kernel_rows
-        return jax.jit(run), args
+        return jax.jit(run), args, report
 
     def launch_count(self, batch: int) -> int:
         """Pallas launches one bank round issues for this batch size.
@@ -263,8 +274,7 @@ class Bank:
             if len(self._compiled) >= self.MAX_COMPILED:
                 self._compiled.pop(next(iter(self._compiled)))
             entry = self._compiled[batch] = self._build(batch)
-        self.last_report = self.report(batch)
-        fn, args = entry
+        fn, args, self.last_report = entry
         with span("bank.launch", **args):
             return fn(a, b)
 

@@ -114,6 +114,35 @@ def test_bank_report_attached_after_execute():
     assert bk.last_report.measured_throughput <= plan.throughput
 
 
+@pytest.mark.parametrize("scheduler", [
+    "round_robin", "greedy", bank.StreamingScheduler(arrival_rate=2)],
+    ids=["round_robin", "greedy", "streaming"])
+def test_report_is_built_once_per_batch_size(scheduler, monkeypatch):
+    """``execute`` keeps one report per compiled batch size: warm calls
+    share it, it equals a fresh ``report``, and it is evicted (and
+    rebuilt equal) with its dispatch."""
+    plan = planner.plan_throughput(32, 32, Fraction(7, 2))
+    bk = bank.Bank(plan, 32, 32, scheduler=scheduler)
+    a, b, _ = _operands(14, 32)
+    bk.execute(a, b)
+    first = bk.last_report
+    bk.execute(a, b)
+    assert bk.last_report is first
+    assert first == bk.report(14)
+    bk.execute(a[:6], b[:6])
+    assert bk.last_report.batch == 6
+    assert bk.last_report == bk.report(6)
+    bk.execute(a, b)
+    assert bk.last_report is first
+    # a third batch size pushes the oldest (14) out of a FIFO of two
+    monkeypatch.setattr(bk, "MAX_COMPILED", 2)
+    bk.execute(a[:3], b[:3])
+    assert set(bk._compiled) == {6, 3}
+    bk.execute(a, b)
+    assert bk.last_report is not first
+    assert bk.last_report == first == bk.report(14)
+
+
 def test_round_robin_schedule_is_work_conserving():
     assign, cycles = bank.round_robin_schedule((1, 1, 1, 2), 56)
     # 3 stars take 16 each, the CT=2 unit 8; last retirement at cycle 16
