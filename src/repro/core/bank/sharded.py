@@ -43,13 +43,16 @@ def _sharded_fn(plan: Plan, bits_a: int, bits_b: int, backend: str,
     from jax import shard_map
     from repro.launch.sharding import bank_batch_spec
 
-    bank = Bank(plan, bits_a, bits_b, backend=backend, scheduler=scheduler)
-    run = bank.dispatch_fn(local)
-    shards = mesh.shape[axis]
-    spec = bank_batch_spec(mesh, axis, 2, local * shards)
-    fn = shard_map(run, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
-                   check_vma=False)
-    args = {"rows": local * shards}
+    # the body runs only on a cache miss, so this span marks one
+    with span("bank.sharded_build"):
+        bank = Bank(plan, bits_a, bits_b, backend=backend,
+                    scheduler=scheduler)
+        run = bank.dispatch_fn(local)
+        shards = mesh.shape[axis]
+        spec = bank_batch_spec(mesh, axis, 2, local * shards)
+        fn = shard_map(run, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=spec, check_vma=False)
+    args = {"rows": local * shards, "shards": shards, "local_rows": local}
     if hasattr(run, "kernel_rows"):
         args["kernel_rows"] = run.kernel_rows * shards
     return jax.jit(fn), args

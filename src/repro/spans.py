@@ -16,6 +16,14 @@ dispatch, with ``rows`` given and, on the fused backend, the
 dispatch, so an ``mcim.bank.report`` inside an ``mcim.mul`` marks a
 dispatch-cache miss: their count divided by the count of ``mcim.mul``
 is the miss share, 0 in a window whose batch sizes are all warm.
+
+On a mesh (``core/bank/sharded.py``) the replicated banks build no
+report.  ``mcim.bank.sharded_build`` marks a miss of the sharded
+dispatch cache (the first call at a new plan, mesh and shard size): it
+holds the build of the replicas' dispatch, whose compile then shows in
+the launch.  That launch also carries ``shards`` (the mesh axis size)
+and ``local_rows`` (rows per shard); its ``rows`` and ``kernel_rows``
+are summed over the shards.
 """
 from __future__ import annotations
 

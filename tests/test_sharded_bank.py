@@ -4,7 +4,9 @@ be bit-exact vs the Python-bigint oracle and vs the single-bank engine,
 for core and kernel backends and both batch-available schedulers.  Also
 pins the backend-registry acceptance: the kernel capability routes every
 planner arch (star, fb, ff, karatsuba CT=3) through Pallas with no core
-fallback."""
+fallback.  On four devices, a design generated with ``replicas=4``
+multiplies through ``CompiledDesign.mul`` as the Python integers do, on
+the fused and the core backend."""
 import os
 import subprocess
 import sys
@@ -88,6 +90,56 @@ def test_sharded_bank_bit_exact_two_devices():
                                        "src"))
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "ALLOK" in out.stdout, out.stdout
+
+
+REPLICATED = r"""
+import dataclasses, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np
+import jax, jax.numpy as jnp
+
+from repro import designs
+from repro.core import limbs as L
+
+assert len(jax.devices()) == 4
+backend = sys.argv[1]
+spec = designs.DesignSpec.from_dict(
+    {"bits_a": 32, "bits_b": 32, "throughput": "7/2", "replicas": 4})
+design = designs.generate(dataclasses.replace(spec, backend=backend))
+assert design.mesh.shape == {"data": 4}
+assert design.bank.backend == backend
+rng = np.random.default_rng(2301)
+a = jnp.asarray(L.random_limbs(rng, (96,), 32))
+b = jnp.asarray(L.random_limbs(rng, (96,), 32))
+out = design.mul(a, b)
+expect = [L.from_limbs(np.asarray(x)) * L.from_limbs(np.asarray(y))
+          for x, y in zip(a, b)]
+assert L.batch_from_limbs(np.asarray(out)) == expect
+assert np.array_equal(np.asarray(out), np.asarray(design.bank.execute(a, b)))
+shards = sorted((s.device.id, s.data.shape) for s in out.addressable_shards)
+assert shards == [(d, (24, 4)) for d in range(4)], shards
+try:
+    design.mul(a[:90], b[:90])
+    raise AssertionError("a batch of 90 split over 4 replicas")
+except ValueError:
+    pass
+print("ALLOK")
+"""
+
+
+@pytest.mark.parametrize("backend", ["fused", "core"])
+def test_replicated_design_matches_integers_on_four_devices(backend):
+    """``generate(replicas=4)`` then ``CompiledDesign.mul``: the sharded
+    path on four devices equals the Python-integer products and the one
+    bank's ``execute``, in four shards of a quarter of the rows each."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    out = subprocess.run([sys.executable, "-c", REPLICATED, backend],
+                         env=env, capture_output=True, text=True,
+                         timeout=600)
     assert out.returncode == 0, out.stderr[-4000:]
     assert "ALLOK" in out.stdout, out.stdout
 

@@ -3,8 +3,9 @@
 ``kernel_rows`` count the rows the dispatch is given and the rows the
 fused kernel computes, after an ``mcim.bank.report`` only on the first
 call of a batch size (the dispatch-cache miss that builds the report).
-On a mesh the sharded dispatch launches once per call and builds no
-report."""
+On a mesh the sharded dispatch launches once per call with its shard
+count and rows per shard, builds no report, and marks the first call at
+a new shard size with ``mcim.bank.sharded_build``."""
 import dataclasses
 import glob
 import json
@@ -158,17 +159,27 @@ a, b = _operands(64)
 want = np.asarray(design.mul(a, b))
 found = traced_spans(
     lambda: [design.mul(a, b).block_until_ready() for _ in range(2)])
+cold = traced_spans(lambda: design.mul(*_operands(32)).block_until_ready())
 local = Bank(design.plan, 32, 32, backend="fused")
+mul, build, launch = cold
 print(json.dumps({"names": [s[0] for s in found],
                   "launch_args": [s[3] for s in found
                                   if s[0] == "mcim.bank.launch"],
                   "kernel_rows_per_shard": _kernel_rows(local, 16),
+                  "cold_names": [s[0] for s in cold],
+                  "cold_launch_args": launch[3],
+                  "cold_kernel_rows_per_shard": _kernel_rows(local, 8),
+                  "cold_build_args": build[3],
+                  "cold_nested": mul[1] <= build[1] and build[2] <= launch[1]
+                  and launch[2] <= mul[2],
                   "same": bool(np.array_equal(
                       np.asarray(design.mul(a, b)), want))}))
 """
 
 
 def test_sharded_path_launches_once_and_builds_no_report():
+    # a warm window: one launch per call, no report and no build; the
+    # first call at 32 rows (8 a shard) builds its dispatch, once
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.pathsep.join([ROOT,
@@ -179,6 +190,14 @@ def test_sharded_path_launches_once_and_builds_no_report():
     got = json.loads(p.stdout.strip().splitlines()[-1])
     assert got["names"] == ["mcim.mul", "mcim.bank.launch"] * 2
     assert got["launch_args"] == [
-        {"rows": 64, "kernel_rows": 4 * got["kernel_rows_per_shard"]}] * 2
+        {"rows": 64, "shards": 4, "local_rows": 16,
+         "kernel_rows": 4 * got["kernel_rows_per_shard"]}] * 2
     assert got["kernel_rows_per_shard"] == 4 * 5
+    assert got["cold_names"] == ["mcim.mul", "mcim.bank.sharded_build",
+                                 "mcim.bank.launch"]
+    assert got["cold_build_args"] == {}
+    assert got["cold_launch_args"] == {
+        "rows": 32, "shards": 4, "local_rows": 8,
+        "kernel_rows": 4 * got["cold_kernel_rows_per_shard"]}
+    assert got["cold_nested"]
     assert got["same"]

@@ -6,7 +6,9 @@ so these tests lower every kernel of the multiplier path natively
 (``interpret=False``) against a described -- not attached -- ``v5e:2x2``
 topology and check that the compiled program holds the Mosaic kernel.
 Nothing runs; a pass says the chip's compiler takes the kernel, not
-that it is fast or correct (the bit-exact tests say the latter).
+that it is fast or correct (the bit-exact tests say the latter).  The
+replicated bank's sharded dispatch is compiled over all four described
+chips, where it must hold the kernel and no collective.
 
 The topology is described inside a fixture, never at import time: the
 TPU library admits one loader per process, and every test worker
@@ -33,8 +35,8 @@ ROWS, TILE = 8192, 512
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One device of a described v5e:2x2, with the compile cache off.
+def topo():
+    """A described v5e:2x2, with the compile cache off.
 
     A compile for a described chip is written to the persistent cache
     but cannot be read back without one, so the cache stays off here.
@@ -42,7 +44,6 @@ def one_chip():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -51,9 +52,16 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One device of the described v5e:2x2."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(shape, dtype, sharding):
@@ -88,3 +96,37 @@ def test_mcim_fold_compiles_for_v5e(one_chip, schedule, ct):
         a, b, ct=ct, tile_b=TILE, schedule=schedule, interpret=False))
     x = _spec((ROWS, limbs), L.LIMB_DTYPE, one_chip)
     _assert_kernel(f.lower(x, x).compile())
+
+
+def test_replicated_bank_compiles_for_four_v5e_chips(topo, monkeypatch):
+    # the sharded dispatch of four tp3p5_w32 replicas, one a chip: each
+    # chip runs the kernel on its own rows, with no collective between
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core.bank import sharded
+    from repro.kernels import runtime
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    spec = designs.DesignSpec.from_dict(
+        {"bits_a": 32, "bits_b": 32, "throughput": "7/2", "replicas": 4,
+         "backend": "fused"})
+    with monkeypatch.context() as m:
+        m.setenv("REPRO_INTERPRET", "0")
+        runtime.reset()
+        try:
+            design = designs.generate(spec, mesh=mesh)
+            fn, args = sharded._sharded_fn(design.plan, 32, 32, "fused",
+                                           spec.scheduler, mesh, "data",
+                                           ROWS)
+            x = _spec((4 * ROWS, design.la), L.LIMB_DTYPE,
+                      NamedSharding(mesh, P("data")))
+            compiled = fn.lower(x, x).compile()
+        finally:
+            runtime.reset()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text, collective
+    assert (args["rows"], args["shards"], args["local_rows"]) == \
+        (4 * ROWS, 4, ROWS)
+    assert compiled.output_shardings.spec[0] == "data"
