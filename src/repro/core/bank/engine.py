@@ -16,8 +16,10 @@ The resulting engine is
     giving per-instance busy cycles and the bank makespan, so measured
     throughput can be checked against ``Plan.throughput``;
   * jit/pjit-compatible: the schedule is static for a given batch size,
-    so ``execute`` lowers to gathers + batched multiplies + scatters
-    (and :mod:`.sharded` can replicate it across a mesh axis).
+    so ``execute`` lowers to static indexing + batched multiplies (the
+    fused backend gives each instance a contiguous run of static slices,
+    the per-instance backends gather and scatter by constant indices),
+    and :mod:`.sharded` can replicate it across a mesh axis.
 """
 from __future__ import annotations
 
@@ -261,8 +263,8 @@ class Bank:
             return self.execute(a[None], b[None])[0]
         batch = a.shape[0]
         if b.shape[0] != batch:
-            # without this, the gather in dispatch_fn clamps out-of-range
-            # op indices and silently returns wrong products
+            # without this, dispatch_fn's constant indices and slices
+            # clamp out-of-range ops and silently return wrong products
             raise ValueError(
                 f"batch mismatch: a has {batch} ops, b has {b.shape[0]}")
         if a.shape[-1] != self.la or b.shape[-1] != self.lb:

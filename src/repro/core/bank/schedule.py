@@ -12,8 +12,9 @@ maps ``(cts, n_ops)`` to a static ``(assignment, makespan)`` pair, where
   * ``makespan`` is the cycle on which the last result retires.
 
 Because the contract is *static* for a given batch size, every policy
-keeps ``Bank.execute`` jit-compatible: the schedule lowers to constant
-gather/scatter indices, never to data-dependent control flow.
+keeps ``Bank.execute`` jit-compatible: the schedule lowers to static
+slices (fused) or constant gather/scatter indices (per instance), never
+to data-dependent control flow.
 
 Policies
 --------

@@ -1,21 +1,27 @@
 """Dispatch glue: one plan round -> one fused megakernel launch.
 
-:func:`make_fused_dispatch` turns a scheduler assignment (which ops run
-on which instance) into a pure closure ``run(a, b) -> products`` that
+:func:`make_fused_dispatch` turns a scheduler assignment (how many ops
+each instance runs) into a pure closure ``run(a, b) -> products`` that
 
-  1. gathers each instance's assigned operand rows into a padded
-     ``(N_INST, R, L)`` block (static numpy indices -- jit lowers them
-     to constant gathers),
+  1. cuts each instance's operand rows out of the batch as one
+     contiguous run of static slices -- instance i takes ops
+     ``[s_i, s_i + c_i)``, ``c_i = len(assign[i])``, in instance
+     order -- into a padded ``(N_INST, R, L)`` block,
   2. runs :func:`.kernel.fused_bank_mul` ONCE -- the whole bank round is
      a single ``pallas_call``,
-  3. scatters the valid rows back to batch order, and
+  3. joins each instance's first ``c_i`` product rows back in batch
+     order (static slices and one concatenate), and
   4. for signed designs, applies the shared two's-complement correction
      pass (:func:`repro.core.mcim.signed_correction`) on the unsigned
      products -- pure jnp, so the round still costs one kernel launch.
 
-Padding rows re-gather op 0's operands; their products are computed and
-dropped (never scattered), which keeps every block rectangular without
-data-dependent control flow.
+Which instance computes which op does not change a product (every
+instance's windows cover all of B), so the device gives instance i a
+contiguous run of the scheduler's count rather than the scheduler's op
+indices: the round holds no gather and no scatter.  Padding rows of an
+instance's block are the next instance's operands (or zeros past the
+batch); their products are computed and dropped, which keeps every
+block rectangular without data-dependent control flow.
 """
 from __future__ import annotations
 
@@ -100,43 +106,51 @@ def make_fused_dispatch(assign, configs, la: int, lb: int, batch: int, *,
 
     ``assign`` is the scheduler's static assignment (tuple per instance
     of op indices into the batch), ``configs`` the flat instance list
-    aligned with it.  The returned closure maps ``(B, LA) x (B, LB) ->
-    (B, LA+LB)`` limb products, bit-exact vs the per-instance path.
-    Its ``kernel_rows`` attribute is the ``N_INST x R`` rows the kernel
-    computes, padding included.
+    aligned with it; only its per-instance counts reach the device.  The
+    returned closure maps ``(B, LA) x (B, LB) -> (B, LA+LB)`` limb
+    products, bit-exact vs the per-instance path.  Its ``kernel_rows``
+    attribute is the ``N_INST x R`` rows the kernel computes, padding
+    included.
     """
     sg = super_geometry(configs, la, lb)
     n_inst = sg.n_instances
     if len(assign) != n_inst:
         raise ValueError(
             f"assignment covers {len(assign)} instances, plan has {n_inst}")
+    counts = [len(ops) for ops in assign]
+    if sum(counts) != batch:
+        raise ValueError(
+            f"assignment holds {sum(counts)} ops, batch has {batch}")
     rows, tile_r = fused_block_rows(assign)
-
-    # static gather: padded rows re-fetch op 0 (computed, never scattered)
-    gather = np.zeros((n_inst, rows), np.int32)
-    inst_ids, row_ids, op_ids = [], [], []
-    for i, ops in enumerate(assign):
-        for r, op in enumerate(ops):
-            gather[i, r] = op
-            inst_ids.append(i)
-            row_ids.append(r)
-            op_ids.append(op)
-    inst_ids = np.asarray(inst_ids, np.int32)
-    row_ids = np.asarray(row_ids, np.int32)
-    op_ids = np.asarray(op_ids, np.int32)
+    starts = np.cumsum([0] + counts[:-1]).tolist()
+    tail = starts[-1] + rows - batch       # the last window's rows past B
 
     table = jnp.asarray(sg.table())
     max_steps = sg.max_steps
     interpret = runtime.interpret_mode()
 
+    # The windows are cut from the transposed (L, B) operand and the
+    # products joined as (L, B), both as 2-D arrays: a (B, L) array with
+    # few limbs keeps B on the TPU's lanes, so these slices are dense and
+    # the one transpose into (and out of) the kernel's limb-minor blocks
+    # is the only full pass over them.  (Slicing the 3-D blocks instead
+    # compiles, for the v5e, to a cut of the lane-padded blocks ahead of
+    # the transpose: one more full pass.)
+    def blocks(x):                         # (B, L) -> (N_INST, R, L)
+        width = x.shape[-1]
+        xt = jnp.pad(x.T, ((0, 0), (0, tail)))
+        runs = jnp.concatenate([xt[:, s:s + rows] for s in starts])
+        return runs.reshape(n_inst, width, rows).transpose(0, 2, 1)
+
     def run(a, b):
-        a_blocks = a[gather]                   # (N_INST, R, LA)
-        b_blocks = b[gather]                   # (N_INST, R, LB)
-        prod = fused_bank_mul(a_blocks, b_blocks, table,
+        prod = fused_bank_mul(blocks(a), blocks(b), table,
                               max_steps=max_steps, tile_r=tile_r,
                               interpret=interpret)
-        out = jnp.zeros((batch, la + lb), L.LIMB_DTYPE)
-        out = out.at[op_ids].set(prod[inst_ids, row_ids])
+        width = la + lb
+        prod_t = prod.transpose(0, 2, 1).reshape(n_inst * width, rows)
+        out = jnp.concatenate(
+            [prod_t[i * width:(i + 1) * width, :c]
+             for i, c in enumerate(counts)], axis=1).T
         if signed:
             out = signed_correction(a, b, out)
         return out

@@ -399,8 +399,9 @@ def check_bank_static(plan, bits_a: int, bits_b: int,
     carrying shape/dtype but NO data: success means no Python control
     flow inspected operand values, and the output shape is the full
     product batch.  Assignment determinism across calls is checked via
-    the scheduler contract; here we additionally diff the gather indices
-    two independently-built dispatches close over.
+    the scheduler contract; here we additionally diff the assignments
+    two independent schedule calls return, which fix the dispatch's
+    static indices and slices.
     """
     import jax
     from repro.core.bank import Bank
@@ -429,7 +430,7 @@ def check_bank_static(plan, bits_a: int, bits_b: int,
     if assign1 != assign2:
         out.append(Violation(
             "contracts", "bank-dispatch-unstable", where,
-            "gather indices differ between two schedule calls for the "
+            "assignments differ between two schedule calls for the "
             "same static batch"))
     return out
 

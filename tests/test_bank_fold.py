@@ -1,13 +1,17 @@
 """Fused bank megakernel: bit-exactness vs the Python-int oracle across
-every registry design point, the single-launch jaxpr contract, ragged
-and signed batches, the fused verifier rules (including seeded
-corruptions and the generate()-time refusal), and the centralized
-interpret-mode runtime flag."""
+every registry design point and scheduler, the single-launch jaxpr
+contract, a dispatch free of index maps, ragged and signed batches, the
+fused verifier rules (including seeded corruptions and the
+generate()-time refusal), and the centralized interpret-mode runtime
+flag."""
 import dataclasses
+import functools
+import re
 from fractions import Fraction
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro import designs, verify
@@ -15,6 +19,7 @@ from repro.core import limbs as L
 from repro.core import planner
 from repro.core.bank import Bank
 from repro.core.bank.backends import cached_mul
+from repro.core.bank.schedule import StreamingScheduler
 from repro.core.mcim import MCIMConfig
 from repro.designs import registry
 from repro.kernels import runtime
@@ -62,12 +67,93 @@ def test_fused_matches_per_instance_paths():
     assert np.array_equal(outs["fused"], outs["core"])
 
 
+# ------------------------------------- contiguous runs, every scheduler
+
+#: the paper's TP=3.5 bank, the widest shipped point and a signed bank
+RUN_DESIGNS = {
+    "tp3p5_w32": registry.get("tp3p5_w32"),
+    "tp5over6_w128": registry.get("tp5over6_w128"),
+    "tp3p5_w32_signed": dataclasses.replace(registry.get("tp3p5_w32"),
+                                            signed=True),
+}
+#: the registered policies; streaming with a rate-limited arrival trace,
+#: which assigns ops unlike round_robin
+RUN_SCHEDULERS = ("round_robin", "greedy",
+                  StreamingScheduler(arrival_rate=2))
+
+
+@functools.lru_cache(maxsize=None)
+def _run_design(name):
+    spec = dataclasses.replace(RUN_DESIGNS[name], backend="fused")
+    return designs.generate(spec)
+
+
+def _expected(a, b, bits, signed):
+    out = []
+    for x, y in zip(np.asarray(a), np.asarray(b)):
+        x, y = L.from_limbs(x), L.from_limbs(y)
+        if signed:
+            x -= (x >> (bits - 1)) << bits
+            y -= (y >> (bits - 1)) << bits
+        out.append((x * y) % (1 << (2 * bits)))
+    return out
+
+
+@pytest.mark.parametrize("batch", (1, 7, 64, 1000))
+@pytest.mark.parametrize("scheduler", RUN_SCHEDULERS,
+                         ids=lambda s: getattr(s, "name", s))
+@pytest.mark.parametrize("name", tuple(RUN_DESIGNS))
+def test_fused_contiguous_runs_bit_exact(name, scheduler, batch):
+    """The device gives each instance a contiguous run of its scheduled
+    op count: products equal the core backend's and the Python-int
+    oracle's bit for bit, and the modeled report is the scheduler's."""
+    design = _run_design(name)
+    spec = design.spec
+    fused = Bank(design.plan, spec.bits_a, spec.bits_b, backend="fused",
+                 scheduler=scheduler)
+    core = Bank(design.plan, spec.bits_a, spec.bits_b, backend="core",
+                scheduler=scheduler)
+    a, b, _ = _operands(batch, spec.bits_a)
+    out = np.asarray(fused.execute(a, b))
+    assert np.array_equal(out, np.asarray(core.execute(a, b)))
+    assert L.batch_from_limbs(out) == _expected(a, b, spec.bits_a,
+                                                spec.signed)
+    report = fused.report(batch)
+    for field in dataclasses.fields(report):
+        assert getattr(fused.last_report, field.name) == \
+            getattr(report, field.name), field.name
+
+
+def _index_ops(text):
+    return re.findall(r"stablehlo\.(gather|scatter)\b", text)
+
+
+@pytest.mark.parametrize("batch", (64, 4096))
+@pytest.mark.parametrize("name", ("tp3p5_w32", "tp5over6_w128"))
+def test_fused_dispatch_lowers_without_index_maps(name, batch):
+    """The lowered fused round holds no gather and no scatter: operands
+    reach the kernel by static slices and products leave it the same
+    way.  The per-instance core path, which does gather, is the probe's
+    control."""
+    design = _run_design(name)
+    lowered = {}
+    for backend in ("fused", "core"):
+        bank = Bank(design.plan, design.spec.bits_a, design.spec.bits_b,
+                    backend=backend)
+        a = jnp.zeros((batch, bank.la), L.LIMB_DTYPE)
+        b = jnp.zeros((batch, bank.lb), L.LIMB_DTYPE)
+        lowered[backend] = jax.jit(bank.dispatch_fn(batch)).lower(
+            a, b).as_text()
+    assert _index_ops(lowered["core"])
+    assert _index_ops(lowered["fused"]) == []
+
+
 # --------------------------------------------------------- ragged batches
 
 @pytest.mark.parametrize("batch", (1, 7, 13, 29))
 def test_fused_ragged_prime_batches(batch):
-    """Prime/ragged batch sizes force padded gather rows; the padding
-    must never leak into the scattered products."""
+    """Prime/ragged batch sizes force padded block rows; the padding
+    must never leak into the joined products."""
     plan = planner.plan_throughput(32, 32, Fraction(7, 2))
     bk = Bank(plan, 32, 32, backend="fused")
     a, b, expect = _operands(batch, 32)

@@ -7,8 +7,10 @@ so these tests lower every kernel of the multiplier path natively
 topology and check that the compiled program holds the Mosaic kernel.
 Nothing runs; a pass says the chip's compiler takes the kernel, not
 that it is fast or correct (the bit-exact tests say the latter).  The
-replicated bank's sharded dispatch is compiled over all four described
-chips, where it must hold the kernel and no collective.
+whole fused dispatch is compiled for one chip, where it must hold the
+kernel and no gather or scatter; the replicated bank's sharded dispatch
+is compiled over all four described chips, where it must hold the
+kernel and no collective.
 
 The topology is described inside a fixture, never at import time: the
 TPU library admits one loader per process, and every test worker
@@ -22,7 +24,8 @@ import jax.numpy as jnp
 
 from repro import designs
 from repro.core import limbs as L
-from repro.kernels.bank_fold import super_geometry
+from repro.kernels import runtime
+from repro.kernels.bank_fold import make_fused_dispatch, super_geometry
 from repro.kernels.bank_fold.kernel import fused_bank_mul
 from repro.kernels.mcim_fold.kernel import mcim_fold_mul
 
@@ -89,6 +92,28 @@ def test_fused_bank_compiles_for_v5e(one_chip, name):
     _assert_kernel(compiled)
 
 
+@pytest.mark.parametrize("name", FUSED_DESIGNS)
+def test_fused_dispatch_compiles_for_v5e(one_chip, name, monkeypatch):
+    # the round as Bank.execute runs it: static slices into the kernel's
+    # blocks and out of them, with no index map left for the compiler
+    bank = designs.generate(name).bank
+    assign, _ = bank.scheduler.schedule(bank._cts, ROWS)
+    with monkeypatch.context() as m:
+        m.setenv("REPRO_INTERPRET", "0")
+        runtime.reset()
+        try:
+            run = make_fused_dispatch(assign, bank.instances, bank.la,
+                                      bank.lb, ROWS)
+            compiled = jax.jit(run).lower(
+                _spec((ROWS, bank.la), L.LIMB_DTYPE, one_chip),
+                _spec((ROWS, bank.lb), L.LIMB_DTYPE, one_chip)).compile()
+        finally:
+            runtime.reset()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    assert "gather(" not in text and "scatter(" not in text
+
+
 @pytest.mark.parametrize("schedule,ct", FOLD_CASES)
 def test_mcim_fold_compiles_for_v5e(one_chip, schedule, ct):
     limbs = L.n_limbs_for_bits(128)
@@ -104,7 +129,6 @@ def test_replicated_bank_compiles_for_four_v5e_chips(topo, monkeypatch):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.core.bank import sharded
-    from repro.kernels import runtime
     mesh = Mesh(np.array(topo.devices), ("data",))
     spec = designs.DesignSpec.from_dict(
         {"bits_a": 32, "bits_b": 32, "throughput": "7/2", "replicas": 4,
