@@ -2,14 +2,12 @@
 derived structural metrics (exact on any backend: op counts, footprints).
 
 Run as a script: ``python benchmarks/kernel_bench.py`` (full sweep) or
-``--smoke`` for the CI subset (the fused-megakernel launch comparison
-plus the structural tables -- the benches that gate on correctness, not
-on CPU wall clock).
+``--smoke`` for the CI subset (the structural tables -- the benches that
+gate on correctness, not on CPU wall clock).
 """
 from __future__ import annotations
 
 import time
-from fractions import Fraction
 
 import numpy as np
 import jax
@@ -98,47 +96,12 @@ def bench_rng_exact():
          f"overhead_vs_f32sum={us_e / max(us_f, 1e-9):.1f}x bit_exact=True")
 
 
-def bench_bank_fold():
-    """Fused bank megakernel vs per-instance launches (TP=3.5 bank).
-
-    The dispatch-tax comparison of the bank_fold work: the same plan,
-    batch and operands through ``backend="kernel"`` (one Pallas launch
-    per busy instance) and ``backend="fused"`` (one launch for the
-    whole round).  Launch counts come from the traced jaxpr, so they
-    are exact on any backend; the wall clocks are interpret-mode CPU
-    figures, indicative only.
-    """
-    from repro.core import planner
-    from repro.core.bank import Bank
-    bits, batch = 16, 14
-    plan = planner.plan_throughput(bits, bits, Fraction(7, 2))
-    a = jnp.asarray(L.random_limbs(RNG, (batch,), bits))
-    b = jnp.asarray(L.random_limbs(RNG, (batch,), bits))
-    expect = [L.from_limbs(np.asarray(x)) * L.from_limbs(np.asarray(y))
-              for x, y in zip(a, b)]
-    times, launches = {}, {}
-    for backend in ("kernel", "fused"):
-        bk = Bank(plan, bits, bits, backend=backend)
-        out = bk.execute(a, b)        # warmup: pays trace + compile
-        assert L.batch_from_limbs(np.asarray(out)) == expect, backend
-        times[backend] = _time(bk.execute, a, b, reps=5)
-        launches[backend] = bk.launch_count(batch)
-        _row(f"kernel.bank_{backend}_16b_tp7_2_b14", times[backend],
-             f"launches_per_round={launches[backend]}")
-    assert launches["fused"] == 1, \
-        f"fused bank round traced {launches['fused']} launches, not 1"
-    _row("kernel.bank_fold_speedup", 0.0,
-         f"fused_vs_per_instance="
-         f"{times['kernel'] / times['fused']:.2f}x "
-         f"launches={launches['kernel']}->{launches['fused']}")
-
-
 ALL = [bench_core_mul, bench_vmem_fold, bench_mcim_kernel_interpret,
-       bench_bank_fold, bench_int8_matmul, bench_rng_exact]
+       bench_int8_matmul, bench_rng_exact]
 
-#: CI subset: structural metrics + the fused launch-count gate; skips
-#: the pure wall-clock benches whose CPU numbers gate nothing
-SMOKE = [bench_vmem_fold, bench_bank_fold]
+#: CI subset: the structural metrics; skips the pure wall-clock benches
+#: whose CPU numbers gate nothing
+SMOKE = [bench_vmem_fold]
 
 
 if __name__ == "__main__":
@@ -146,8 +109,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser(
         description="kernel/system microbenchmarks")
     ap.add_argument("--smoke", action="store_true",
-                    help="CI subset (fused launch gate + structural "
-                         "tables)")
+                    help="CI subset (structural tables)")
     args = ap.parse_args()
     print("name,us_per_call,derived")
     for fn in (SMOKE if args.smoke else ALL):

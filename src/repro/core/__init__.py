@@ -13,7 +13,8 @@ for direct use:
   planner          -- design-point selection (paper Table VIII policy)
   timing_model     -- clock/latency model filtering that selection
   bank             -- executable multiplier banks for planner Plans
-                      (pluggable schedulers/backends + sharded execution)
+                      (pluggable schedulers, the core and fused backends,
+                      sharded execution)
   area_model       -- ASIC-area cost model used by benchmarks/
   power_model      -- switching-energy / peak-power cost model
                       (the paper's 33%-energy / 65%-peak-power claims)

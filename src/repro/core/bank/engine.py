@@ -8,9 +8,11 @@ paper's Sec. V-E use case issues work to the silicon bank.
 
 The resulting engine is
 
-  * bit-exact: every instance runs its registered :mod:`.backends`
-    multiplier (pure-jnp ``mcim_mul`` or a Pallas kernel), so the
-    reassembled batch equals the Python-int oracle regardless of policy;
+  * bit-exact on either of its two backends: ``"core"`` runs each
+    instance's pure-jnp ``mcim_mul`` (the CPU path and the reference),
+    ``"fused"`` runs the whole bank round as ONE ``kernels.bank_fold``
+    Pallas launch (the device path); both equal the Python-int oracle
+    regardless of policy;
   * cycle-accounted: the dispatch schedule is simulated once per batch
     size, and ``execute`` keeps its report beside the compiled dispatch,
     giving per-instance busy cycles and the bank makespan, so measured
@@ -18,8 +20,8 @@ The resulting engine is
   * jit/pjit-compatible: the schedule is static for a given batch size,
     so ``execute`` lowers to static indexing + batched multiplies (the
     fused backend gives each instance a contiguous run of static slices,
-    the per-instance backends gather and scatter by constant indices),
-    and :mod:`.sharded` can replicate it across a mesh axis.
+    the core backend gathers and scatters by constant indices), and
+    :mod:`.sharded` can replicate it across a mesh axis.
 """
 from __future__ import annotations
 
@@ -32,12 +34,42 @@ import jax
 import jax.numpy as jnp
 
 from .. import limbs as L
-from ..mcim import MCIMConfig
+from ..mcim import MCIMConfig, mcim_mul
 from ..planner import Plan
 from repro.spans import span
-from .backends import BACKENDS, cached_mul, get_backend
 from .schedule import (completion_cycles, get_scheduler,
                        histogram_percentile, latency_histogram)
+
+
+#: the bank's execution substrates: pure-jnp per instance, or the whole
+#: round as one fused Pallas launch
+BACKENDS = ("core", "fused")
+
+
+def _working_set(backend: str, instances: tuple, la: int, lb: int,
+                 tile_b: int) -> int:
+    """Per-step VMEM working set of the bank (the TPU analogue of the
+    paper's silicon area).
+
+    Core: the sum over instances of each one's ``mcim_fold`` footprint
+    (the design's own datapaths side by side).  Fused: the instances
+    time-share ONE windowed-schoolbook datapath whose footprint does not
+    depend on the instance, so the bank's figure is that one footprint.
+    """
+    if backend == "fused":
+        from repro.kernels.bank_fold import vmem_bytes_per_step
+        return vmem_bytes_per_step(la, lb, tile_b)
+    from repro.kernels.mcim_fold import vmem_bytes_per_step
+    total = 0
+    for cfg in instances:
+        if cfg.arch == "star":
+            total += vmem_bytes_per_step(la, lb, 1, tile_b)
+        elif cfg.arch in ("ff", "karatsuba"):
+            total += vmem_bytes_per_step(la, lb, cfg.ct, tile_b,
+                                         schedule=cfg.arch)
+        else:
+            total += vmem_bytes_per_step(la, lb, cfg.ct, tile_b)
+    return total
 
 
 # ------------------------------------------------------------------ reports
@@ -61,7 +93,7 @@ class BankReport:
     cycles: int                       # bank makespan
     instances: tuple                  # tuple[InstanceReport]
     plan_throughput: Fraction
-    working_set_bytes: int            # sum of per-instance VMEM footprints
+    working_set_bytes: int            # per-step VMEM footprint of the bank
     scheduler: str = "round_robin"    # policy that produced the makespan
     #: per-request latency histogram, sorted ((cycles, count), ...):
     #: admission (the policy's arrival trace, cycle 0 for batch
@@ -112,14 +144,12 @@ class Bank:
     ``execute(a, b)`` multiplies a batch of limb vectors
     (B, LA) x (B, LB) -> (B, LA+LB) bit-exactly; ``last_report`` /
     ``report(batch)`` exposes the cycle accounting.  ``backend`` picks
-    the instance substrate ("core" | "kernel" | "fused"), ``scheduler``
-    the dispatch policy ("round_robin" | "greedy" | "streaming" or any
-    registered :class:`~.schedule.Scheduler`).
-
-    The "fused" backend collapses the whole bank round into ONE
-    ``kernels.bank_fold`` megakernel launch (vs one launch per busy
-    instance on "kernel"); :meth:`launch_count` reports the difference
-    from the traced jaxpr.
+    the substrate ("core": pure-jnp ``mcim_mul`` per instance; "fused":
+    the whole bank round as ONE ``kernels.bank_fold`` megakernel
+    launch), ``scheduler`` the dispatch policy ("round_robin" |
+    "greedy" | "streaming" or any registered
+    :class:`~.schedule.Scheduler`).  :meth:`launch_count` reads the
+    launches of one round from the traced jaxpr.
     """
 
     # each distinct batch size compiles its own dispatch; bound the set
@@ -145,13 +175,9 @@ class Bank:
         if not self.instances:
             raise ValueError("plan has no instances")
         self._cts = tuple(cfg.ct for cfg in self.instances)
-        self._backends = tuple(get_backend(cfg.arch, backend)
+        if backend == "core":
+            self._muls = tuple(functools.partial(mcim_mul, config=cfg)
                                for cfg in self.instances)
-        # cached across Bank instantiations: same instance shape -> same
-        # callable -> shared jit trace (see backends.cached_mul)
-        self._muls = tuple(cached_mul(cfg.arch, backend, cfg,
-                                      self.la, self.lb)
-                           for cfg in self.instances)
         signedness = {cfg.signed for cfg in self.instances}
         if backend == "fused" and len(signedness) > 1:
             raise ValueError(
@@ -187,13 +213,8 @@ class Bank:
             if hasattr(sched, "arrivals_for") else (0,) * batch
         finish = completion_cycles(self._cts, assign, arrivals)
         hist = latency_histogram(f - a for f, a in zip(finish, arrivals))
-        footprints = tuple(
-            be.working_set(cfg, self.la, self.lb, self.tile_b)
-            for cfg, be in zip(self.instances, self._backends))
-        # fused instances time-share ONE datapath, so the bank's working
-        # set is the largest instance footprint, not the sum
-        ws = max(footprints) if self.backend == "fused" \
-            else sum(footprints)
+        ws = _working_set(self.backend, self.instances, self.la, self.lb,
+                          self.tile_b)
         return BankReport(batch=batch, cycles=cycles, instances=insts,
                           plan_throughput=self.plan.throughput,
                           working_set_bytes=ws, scheduler=sched.name,
@@ -249,10 +270,9 @@ class Bank:
         """Pallas launches one bank round issues for this batch size.
 
         Traced from the dispatch jaxpr (no execution): exactly 1 on the
-        fused path, one per busy instance on the per-instance kernel
-        path, 0 on the pure-jnp core path.
+        fused path, 0 on the pure-jnp core path.
         """
-        from repro.launch.roofline import count_pallas_launches
+        from repro.kernels.introspect import count_pallas_launches
         a = jnp.zeros((batch, self.la), L.LIMB_DTYPE)
         b = jnp.zeros((batch, self.lb), L.LIMB_DTYPE)
         return count_pallas_launches(self.dispatch_fn(batch), a, b)

@@ -10,16 +10,16 @@ concatenate back bit-exactly -- each multiplication is computed by
 exactly one instance of one bank replica, so ``sharded_execute`` equals
 the single-bank oracle product-for-product.
 
-Partition specs come from :func:`repro.launch.sharding.bank_batch_spec`
-(the same divisibility-checked spec machinery the model runtime uses),
-so the bank composes with the launch layer's meshes instead of invented
-ad-hoc shardings.
+The batch is split by :func:`bank_batch_spec`, which refuses a batch
+that does not divide evenly instead of replicating it.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from .. import limbs as L
 from ..planner import Plan
@@ -27,10 +27,23 @@ from repro.spans import span
 from .engine import Bank, BankReport
 
 
+def bank_batch_spec(mesh, axis: str, ndim: int, batch_dim_size: int) -> P:
+    """Spec for a multiplier-bank batch replicated along one mesh axis.
+
+    Bank replicas each need an equal shard, so a batch that does not
+    divide over the axis is an error, not a fallback to replication."""
+    if axis not in mesh.axis_names:
+        raise ValueError(f"axis {axis!r} not in mesh axes {mesh.axis_names}")
+    if batch_dim_size % mesh.shape[axis]:
+        raise ValueError(
+            f"batch {batch_dim_size} not divisible by mesh axis "
+            f"{axis!r} size {mesh.shape[axis]}")
+    return P(*((axis,) + (None,) * (ndim - 1)))
+
+
 def _local_batch(batch: int, mesh, axis: str) -> int:
     # bank_batch_spec is the single owner of the axis-membership and
     # divisibility validation; this just derives the shard size from it
-    from repro.launch.sharding import bank_batch_spec
     bank_batch_spec(mesh, axis, 2, batch)
     return batch // mesh.shape[axis]
 
@@ -38,11 +51,6 @@ def _local_batch(batch: int, mesh, axis: str) -> int:
 @functools.lru_cache(maxsize=64)
 def _sharded_fn(plan: Plan, bits_a: int, bits_b: int, backend: str,
                 scheduler: str, mesh, axis: str, local: int):
-    # Lazy imports: core must stay importable without touching the
-    # launch layer (and jax device state) at module-import time.
-    from jax import shard_map
-    from repro.launch.sharding import bank_batch_spec
-
     # the body runs only on a cache miss, so this span marks one
     with span("bank.sharded_build"):
         bank = Bank(plan, bits_a, bits_b, backend=backend,

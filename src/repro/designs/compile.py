@@ -259,27 +259,13 @@ class CompiledDesign:
 
 # ---------------------------------------------------------------- generate
 
-def _resolve_backend(spec: DesignSpec, plan: planner.Plan) -> str:
-    if spec.backend == "kernel" and spec.signed:
-        raise DesignError("the kernel capability is unsigned-only; use "
-                          "backend='core', 'fused' or 'auto' for signed "
-                          "designs (fused retires signedness through the "
-                          "shared correction pass)")
+def _resolve_backend(spec: DesignSpec) -> str:
+    """The spec's backend; "auto" is the fused megakernel where Pallas
+    is native, pure-jnp elsewhere (the CPU container would pay
+    interpret-mode kernel cost for nothing)."""
     if spec.backend != "auto":
         return spec.backend
-    # auto: one fused megakernel launch per round where Pallas is
-    # native and every instance arch has a fused backend; per-instance
-    # kernels as the unsigned fallback; pure-jnp elsewhere (the CPU
-    # container would pay interpret-mode kernel cost for nothing)
-    if jax.default_backend() == "tpu":
-        from repro.core.bank.backends import registered_backends
-        registered = set(registered_backends())
-        if all((cfg.arch, "fused") in registered
-               for _, cfg in plan.configs):
-            return "fused"
-        if not spec.signed:
-            return "kernel"
-    return "core"
+    return "fused" if jax.default_backend() == "tpu" else "core"
 
 
 def _achieved_throughput(plan: planner.Plan):
@@ -335,7 +321,7 @@ def _plan_with_timing(spec: DesignSpec):
     # cannot prove overflow-safe and schedule-conformant never compiles
     verify.assert_plan(spec.bits_a, spec.bits_b, plan.configs,
                        plan.throughput)
-    # dataflow gate: every Pallas launch the plan implies must prove
+    # dataflow gate: the fused Pallas launch the plan implies must prove
     # hazard-free, in-bounds and within its VMEM model -- without
     # executing (cached per distinct launch geometry)
     verify.assert_plan_dataflow(spec.bits_a, spec.bits_b, plan.configs)
@@ -378,7 +364,7 @@ def generate(spec: DesignSpec, mesh=None) -> CompiledDesign:
         from .registry import get
         spec = get(spec)
     plan, fallback = _plan_with_timing(spec)
-    backend = _resolve_backend(spec, plan)
+    backend = _resolve_backend(spec)
     bank = Bank(plan, spec.bits_a, spec.bits_b, backend=backend,
                 scheduler=spec.scheduler)
     return CompiledDesign(spec, plan, bank,
@@ -433,7 +419,7 @@ def compile_plan(spec: DesignSpec, configs, mesh=None) -> CompiledDesign:
     verify.assert_plan(spec.bits_a, spec.bits_b, plan.configs,
                        plan.throughput)
     verify.assert_plan_dataflow(spec.bits_a, spec.bits_b, plan.configs)
-    backend = _resolve_backend(spec, plan)
+    backend = _resolve_backend(spec)
     bank = Bank(plan, spec.bits_a, spec.bits_b, backend=backend,
                 scheduler=spec.scheduler)
     return CompiledDesign(spec, plan, bank,

@@ -39,8 +39,10 @@ from fractions import Fraction
 #: exactly the denominator plan_throughput will use, so a spec's
 #: throughput always equals its compiled plan's.
 from repro.core.planner import MAX_TP_DENOMINATOR, OBJECTIVES
+from repro.core.bank import BACKENDS
 
-_BACKENDS = ("auto", "core", "kernel", "fused")
+#: "auto" resolves to "fused" on a TPU and to "core" elsewhere
+_BACKENDS = ("auto",) + BACKENDS
 _SPEC_VERSION = 1
 
 
@@ -67,7 +69,7 @@ class DesignSpec:
     strict_timing: bool = False
     signed: bool = False
     scheduler: str = "round_robin"
-    backend: str = "auto"               # auto | core | kernel | fused
+    backend: str = "auto"               # auto | core | fused
     replicas: int = 1                   # bank replicas over a mesh axis
     mesh_axis: str = "data"
     objective: str = "area"             # planner ranking: area | energy
@@ -84,7 +86,8 @@ class DesignSpec:
         if self.latency_budget is not None and self.latency_budget < 1:
             raise DesignError("latency_budget must be >= 1 cycle")
         if self.backend not in _BACKENDS:
-            raise DesignError(f"backend must be one of {_BACKENDS}")
+            raise DesignError(f"backend must be one of {_BACKENDS}, "
+                              f"got {self.backend!r}")
         if self.replicas < 1:
             raise DesignError("replicas must be >= 1")
         if self.objective not in OBJECTIVES:

@@ -118,24 +118,3 @@ def model_flops(n_active_params: int, tokens: int, kind: str) -> float:
     mult = 6.0 if kind == "train" else 2.0
     return mult * n_active_params * tokens
 
-
-# ------------------------------------------------------------ launch counting
-
-def _count_pallas_eqns(jaxpr) -> int:
-    """Recursively count ``pallas_call`` equations in a jaxpr."""
-    from repro.verify import jaxpr_walk
-    return jaxpr_walk.count_primitive(jaxpr, "pallas_call")
-
-
-def count_pallas_launches(fn, *args) -> int:
-    """Kernel launches one call of ``fn(*args)`` issues.
-
-    Traces ``fn`` (no execution) and counts ``pallas_call`` primitives
-    recursively through nested jaxprs (jit/closed-call bodies) via the
-    shared ``verify.jaxpr_walk`` traversal.  This is the dispatch-tax
-    metric of the fused-bank work: a per-instance bank round costs one
-    launch per busy instance, the fused megakernel exactly one.
-    """
-    import jax
-    closed = jax.make_jaxpr(fn)(*args)
-    return _count_pallas_eqns(closed.jaxpr)

@@ -46,21 +46,6 @@ def batch_spec(mesh, ndim: int, batch_dim_size: int) -> P:
     return P(*((first,) + (None,) * (ndim - 1)))
 
 
-def bank_batch_spec(mesh, axis: str, ndim: int, batch_dim_size: int) -> P:
-    """Spec for a multiplier-bank batch replicated along one mesh axis.
-
-    Unlike :func:`batch_spec` (which silently replicates when the batch
-    does not divide), bank replicas each need an equal shard, so
-    non-divisible batches are an error, not a fallback."""
-    if axis not in mesh.axis_names:
-        raise ValueError(f"axis {axis!r} not in mesh axes {mesh.axis_names}")
-    if batch_dim_size % mesh.shape[axis]:
-        raise ValueError(
-            f"batch {batch_dim_size} not divisible by mesh axis "
-            f"{axis!r} size {mesh.shape[axis]}")
-    return P(*((axis,) + (None,) * (ndim - 1)))
-
-
 def attn_cache_spec(mesh, shape) -> P:
     """shape: (*prefix, B, S, KV, hd).
 

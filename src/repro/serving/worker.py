@@ -229,8 +229,8 @@ class Worker:
 
     # ---------------------------------------------------------- replicas
     def _new_replica(self, index: int) -> Replica:
-        # same plan/backend on every replica: cached_mul shares the
-        # per-instance jit traces, so replica N+1 is cheap to spin up
+        # same plan/backend on every replica; each replica's Bank traces
+        # and compiles its own dispatch per batch size (none are shared)
         bank = Bank(self.plan, self.spec.bits_a, self.spec.bits_b,
                     backend=self.backend,
                     scheduler=self.design.bank.scheduler.name)

@@ -37,7 +37,7 @@ from .contracts import (check_coverage, check_widths, check_throughput,
 from .lint import lint_tree, lint_source
 
 __all__ = [
-    "intervals", "contracts", "lint", "dataflow", "vmem", "jaxpr_walk",
+    "intervals", "contracts", "lint", "dataflow", "vmem",
     "IntervalReport", "Violation", "VerificationError", "DataflowError",
     "analyze", "check_coverage", "check_widths", "check_throughput",
     "check_fused_schedule", "check_fused_widths", "check_fused_plan",
@@ -47,10 +47,9 @@ __all__ = [
     "verify_plan_dataflow", "assert_plan_dataflow",
 ]
 
-#: substrates swept per instance (kernel skipped for signed configs,
-#: whose capability is core-only; fused handles signedness through the
-#: bank-wide correction pass, so it is swept unconditionally)
-_SUBSTRATES = ("core", "kernel", "fused")
+#: substrates swept per instance: the two the bank can run (fused
+#: handles signedness through the bank-wide correction pass)
+_SUBSTRATES = ("core", "fused")
 
 
 class VerificationError(ValueError):
@@ -77,7 +76,7 @@ class DataflowError(VerificationError):
 
 
 # after DataflowError: dataflow imports the class at raise time
-from . import dataflow, jaxpr_walk, vmem            # noqa: E402
+from . import dataflow, vmem                        # noqa: E402
 from .dataflow import verify_plan_dataflow          # noqa: E402
 
 
@@ -91,12 +90,9 @@ def verify_instance(bits_a: int, bits_b: int, cfg) -> tuple:
     """
     out = []
     out.extend(contracts.check_coverage(bits_a, bits_b, cfg))
-    out.extend(contracts.check_widths(bits_a, bits_b, cfg))
     out.extend(contracts.check_fused_schedule(bits_a, bits_b, cfg))
     out.extend(contracts.check_fused_widths(bits_a, bits_b, cfg))
     for sub in _SUBSTRATES:
-        if sub == "kernel" and cfg.signed:
-            continue
         out.extend(intervals.analyze(bits_a, bits_b, cfg,
                                      substrate=sub).violations)
     return tuple(out)
@@ -133,8 +129,8 @@ def assert_plan_dataflow(bits_a: int, bits_b: int, configs,
                          budget=None) -> None:
     """Raise :class:`DataflowError` unless every launch proves safe.
 
-    The fourth plan-time gate: traces (never executes) the per-instance
-    and fused Pallas launches the plan implies and rejects hazards,
+    The fourth plan-time gate: traces (never executes) the one fused
+    Pallas launch the bank issues for the plan and rejects hazards,
     out-of-bounds windows/block indices and VMEM model/budget breaks.
     Results are cached per distinct launch geometry inside
     :mod:`.dataflow`, so repeated gating is cheap.

@@ -7,17 +7,21 @@ Sections of the sweep (each contributes to ``VERIFY_report.json``):
   vocabulary      every instance architecture the autotuner can emit
                   (star; fb/ff over the CT set; Karatsuba levels x
                   adders; signed variants) at widths 8..128, on both
-                  substrates;
+                  bank substrates (core and fused);
   decompositions  sample fractional TPs decomposed by
                   ``autotune.candidates.enumerate_configs``, every
                   candidate checked for throughput + instance safety;
   fused           bank-level fused-megakernel contracts of every
                   registry plan (super-geometry idle masks, SMEM table
                   consistency, window coverage, scratch domination);
-  dataflow        jaxpr-level static proofs of every Pallas launch the
-                  registry + vocabulary imply (both substrates): hazard
-                  freedom, window/block bounds, VMEM model/budget, and
-                  the static FLOPs/HBM roofline per launch;
+  dataflow        jaxpr-level static proofs of the fused launch every
+                  registry plan and vocabulary instance implies, and of
+                  the standalone kernels: hazard freedom, window/block
+                  bounds, VMEM model/budget, and the static FLOPs/HBM
+                  roofline per launch;
+  mcim_fold       the standalone per-instance ``mcim_fold`` kernel,
+                  once per (schedule, CT) of the vocabulary at each
+                  width: interval walk, scratch/out widths and dataflow;
   schedulers      determinism/completeness/makespan contracts of every
                   registered dispatch policy;
   bank            ``Bank.dispatch_fn`` staticness under eval_shape;
@@ -180,13 +184,13 @@ def sweep_fused() -> tuple:
 def sweep_dataflow(widths) -> tuple:
     """Static dataflow proofs of every Pallas launch the repo can plan.
 
-    Registry plans and the full autotuner vocabulary (both substrates:
-    per-instance ``mcim_fold`` launches and the fused megakernel), the
-    standalone kernels, and ragged/prime batch shapes through the
-    tiler.  Per launch: hazard freedom, window/block bounds, the VMEM
-    model/budget and the static roofline (``arith_intensity``).
-    Distinct launch geometries are analyzed once (cached), so the sweep
-    cost scales with geometry variety, not design count.
+    The fused launch of every registry plan and of every autotuner
+    vocabulary instance, the standalone kernels, and ragged/prime batch
+    shapes through the ``mcim_fold`` tiler.  Per launch: hazard freedom,
+    window/block bounds, the VMEM model/budget and the static roofline
+    (``arith_intensity``).  Distinct launch geometries are analyzed once
+    (cached), so the sweep cost scales with geometry variety, not design
+    count.
     """
     from repro.designs import registry
     from repro.designs.compile import _plan_with_timing
@@ -194,10 +198,7 @@ def sweep_dataflow(widths) -> tuple:
     results, violations = [], []
 
     def plan_entry(bits_a, bits_b, configs):
-        reps = []
-        for substrate in ("kernel", "fused"):
-            reps.extend(dataflow.analyze_plan(bits_a, bits_b, configs,
-                                              substrate=substrate))
+        reps = dataflow.analyze_plan(bits_a, bits_b, configs)
         vs = [v for rep in reps for v in rep.violations]
         return reps, vs
 
@@ -239,6 +240,31 @@ def sweep_dataflow(widths) -> tuple:
         violations.extend(rep.violations)
         results.append({"launch": rep.name, "batch": batch,
                         "grid": list(rep.grid), "ok": rep.ok})
+    return results, violations
+
+
+def sweep_mcim_fold(widths) -> tuple:
+    """The standalone ``mcim_fold`` kernel (``big_mul``), which no bank
+    backend launches: once per (schedule, CT) of the unsigned vocabulary
+    at each width, its interval walk, its scratch/out widths against
+    that walk, and the dataflow proofs of its launch."""
+    from . import dataflow
+    results, violations = [], []
+    for w in widths:
+        seen = set()
+        for cfg in _vocabulary():
+            key = dataflow._instance_params(cfg)
+            if cfg.signed or key in seen:
+                continue
+            seen.add(key)
+            vs = list(intervals.analyze(w, w, cfg,
+                                        substrate="kernel").violations)
+            vs.extend(contracts.check_widths(w, w, cfg))
+            [rep] = dataflow.analyze_plan(w, w, ((1, cfg),),
+                                          substrate="kernel")
+            vs.extend(rep.violations)
+            violations.extend(vs)
+            results.append({"bits": w, "launch": rep.name, "ok": not vs})
     return results, violations
 
 
@@ -293,6 +319,11 @@ def main(argv=None) -> int:
     all_violations.extend(vs)
     print(f"  dataflow:       {len(sections['dataflow'])} launch "
           f"points, {len(vs)} violations")
+
+    sections["mcim_fold"], vs = sweep_mcim_fold(widths)
+    all_violations.extend(vs)
+    print(f"  mcim_fold:      {len(sections['mcim_fold'])} standalone "
+          f"launches, {len(vs)} violations")
 
     # the serving package registers its slo_edf policy at import: pull
     # it in before the sweep so an unverifiable serving scheduler fails

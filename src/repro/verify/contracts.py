@@ -438,17 +438,15 @@ def check_bank_static(plan, bits_a: int, bits_b: int,
 # ------------------------------------------------------------- aggregate
 
 def check_plan(bits_a: int, bits_b: int, configs, throughput,
-               substrates=("core", "kernel", "fused")) -> list:
+               substrates=("core", "fused")) -> list:
     """Full contract sweep of one plan: throughput sum + per-instance
-    coverage, widths and interval safety on every substrate, plus the
-    fused super-geometry contracts when the fused substrate is swept."""
+    coverage and interval safety on every substrate the bank runs, plus
+    the fused super-geometry contracts when the fused substrate is
+    swept."""
     out = list(check_throughput(configs, throughput))
     for _, cfg in configs:
         out.extend(check_coverage(bits_a, bits_b, cfg))
-        out.extend(check_widths(bits_a, bits_b, cfg))
         for sub in substrates:
-            if sub == "kernel" and cfg.signed:
-                continue          # the kernel capability is unsigned-only
             rep = intervals.analyze(bits_a, bits_b, cfg, substrate=sub)
             out.extend(rep.violations)
         if "fused" in substrates:

@@ -51,7 +51,8 @@ import functools
 
 import numpy as np
 
-from repro.verify import jaxpr_walk, vmem
+from repro.kernels import introspect
+from repro.verify import vmem
 from repro.verify.intervals import Violation
 
 _ANALYZER = "dataflow"
@@ -696,7 +697,7 @@ def _eval_index_map(interp, closed, args):
 
 def _program_id_axes(kernel_jaxpr) -> tuple:
     axes = set()
-    for eqn in jaxpr_walk.walk(kernel_jaxpr, into_pallas=True):
+    for eqn in introspect.walk(kernel_jaxpr, into_pallas=True):
         if eqn.primitive.name == "program_id":
             axes.add(eqn.params["axis"])
     return tuple(sorted(axes))
@@ -724,7 +725,7 @@ def analyze_contract(contract, budget=None):
         closed = contract.trace()
     except Exception as e:                   # noqa: BLE001
         return fail("trace-error", f"tracing raised {e!r}")
-    calls = jaxpr_walk.find_pallas_calls(closed.jaxpr)
+    calls = introspect.find_pallas_calls(closed.jaxpr)
     if len(calls) != 1:
         return fail("launch-count",
                     f"expected exactly 1 pallas_call, traced "
@@ -997,9 +998,9 @@ def analyze_plan(bits_a: int, bits_b: int, configs,
                  substrate: str = "fused", budget=None) -> tuple:
     """LaunchReports of every distinct launch a plan implies.
 
-    ``substrate="kernel"``: one per-instance ``mcim_fold`` launch per
-    distinct (schedule, CT) in the plan.  ``substrate="fused"``: the
-    one megakernel launch of the whole bank.  Signed configs analyze
+    ``substrate="fused"``: the one megakernel launch of the whole bank,
+    the launch the bank issues.  ``substrate="kernel"``: one standalone
+    ``mcim_fold`` launch per distinct (schedule, CT) in the plan.  Signed configs analyze
     identically -- the correction pass is pure jnp outside the kernel,
     so the Pallas launch is the unsigned one.
     """
@@ -1027,13 +1028,11 @@ def analyze_plan(bits_a: int, bits_b: int, configs,
 
 def verify_plan_dataflow(bits_a: int, bits_b: int, configs,
                          budget=None) -> tuple:
-    """All dataflow violations of a plan, both substrates."""
-    out = []
-    for substrate in ("kernel", "fused"):
-        for rep in analyze_plan(bits_a, bits_b, configs,
-                                substrate=substrate, budget=budget):
-            out.extend(rep.violations)
-    return tuple(out)
+    """All dataflow violations of the one fused launch the bank issues
+    for a plan (the core backend issues no Pallas launch)."""
+    return tuple(v for rep in analyze_plan(bits_a, bits_b, configs,
+                                           budget=budget)
+                 for v in rep.violations)
 
 
 def plan_static_stats(bits_a: int, bits_b: int, configs) -> dict:

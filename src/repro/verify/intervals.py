@@ -45,7 +45,8 @@ from repro.kernels.bank_fold.geometry import fused_windows
 
 U32_MAX = L.U32_MAX
 
-#: execution substrates a design can be proven for (cf. bank.backends)
+#: execution substrates a design can be proven for: the bank's two
+#: backends, and the standalone per-instance ``mcim_fold`` kernel
 SUBSTRATES = ("core", "kernel", "fused")
 
 

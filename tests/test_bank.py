@@ -1,7 +1,7 @@
 """Bank execution engine: bit-exactness vs the Python-int oracle and
 cycle accounting vs Plan.throughput, for every plan the planner emits
 at the paper's fractional design points -- under every scheduler policy
-and backend capability.  Also covers the generalized mcim_fold kernel
+and on both backends.  Also covers the generalized mcim_fold kernel
 (FB + FF schedules for CT in {2, 3, 4, 6}, the folded Karatsuba CT=3
 schedule, and awkward-batch tile padding)."""
 from fractions import Fraction
@@ -30,21 +30,13 @@ def _operands(batch, bits):
 
 # --------------------------------------------------------------- bit-exact
 
+@pytest.mark.parametrize("backend", bank.BACKENDS)
 @pytest.mark.parametrize("bits", BITS)
 @pytest.mark.parametrize("tp", TPS, ids=str)
-def test_bank_bit_exact_core(tp, bits):
+def test_bank_bit_exact_core(tp, bits, backend):
     plan = planner.plan_throughput(bits, bits, tp)
     a, b, expect = _operands(3 * max(tp.numerator, 1), bits)
-    out = bank.execute(plan, a, b)
-    assert L.batch_from_limbs(np.asarray(out)) == expect
-
-
-@pytest.mark.parametrize("bits", BITS)
-@pytest.mark.parametrize("tp", TPS, ids=str)
-def test_bank_bit_exact_kernel(tp, bits):
-    plan = planner.plan_throughput(bits, bits, tp)
-    a, b, expect = _operands(2 * max(tp.numerator, 1), bits)
-    out = bank.execute(plan, a, b, backend="kernel")
+    out = bank.execute(plan, a, b, backend=backend)
     assert L.batch_from_limbs(np.asarray(out)) == expect
 
 
@@ -63,11 +55,11 @@ def test_bank_bit_exact_any_scheduler(tp, scheduler):
 
 def test_bank_kernel_backend_karatsuba_arch():
     """A karatsuba-bearing plan (128b, CT=3) runs entirely through the
-    Pallas path: the registry has no core fallback to hide in."""
+    fused Pallas launch, as a 3-window schoolbook."""
     plan = planner.plan_throughput(128, 128, Fraction(1, 3))
     assert any(cfg.arch == "karatsuba" for _, cfg in plan.configs)
     a, b, expect = _operands(6, 128)
-    out = bank.execute(plan, a, b, backend="kernel")
+    out = bank.execute(plan, a, b, backend="fused")
     assert L.batch_from_limbs(np.asarray(out)) == expect
 
 

@@ -18,14 +18,13 @@ from repro import designs, verify
 from repro.core import limbs as L
 from repro.core import planner
 from repro.core.bank import Bank
-from repro.core.bank.backends import cached_mul
 from repro.core.bank.schedule import StreamingScheduler
 from repro.core.mcim import MCIMConfig
 from repro.designs import registry
 from repro.kernels import runtime
 from repro.kernels.bank_fold import (fused_ct, fused_windows,
                                      super_geometry)
-from repro.launch.roofline import count_pallas_launches
+from repro.kernels.introspect import count_pallas_launches
 
 RNG = np.random.default_rng(47)
 
@@ -55,15 +54,14 @@ def test_fused_bit_exact_every_registry_point(name):
 
 
 def test_fused_matches_per_instance_paths():
-    """Same plan, same operands: fused == kernel == core, bitwise."""
+    """Same plan, same operands: fused == core, bitwise."""
     plan = planner.plan_throughput(32, 32, Fraction(7, 2))
     a, b, expect = _operands(11, 32)
     outs = {}
-    for backend in ("core", "kernel", "fused"):
+    for backend in ("core", "fused"):
         bk = Bank(plan, 32, 32, backend=backend)
         outs[backend] = np.asarray(bk.execute(a, b))
         assert L.batch_from_limbs(outs[backend]) == expect
-    assert np.array_equal(outs["fused"], outs["kernel"])
     assert np.array_equal(outs["fused"], outs["core"])
 
 
@@ -176,24 +174,16 @@ def test_fused_signed_bit_exact():
     assert design.bank.launch_count(9) == 1
 
 
-def test_kernel_backend_still_refuses_signed():
-    spec = designs.DesignSpec(32, 32, Fraction(1, 2), signed=True,
-                              backend="kernel")
-    with pytest.raises(designs.DesignError):
-        designs.generate(spec)
-
-
 # ------------------------------------------------------------ launch count
 
 def test_fused_single_launch_per_round():
-    """The tentpole contract: a fused bank round traces to EXACTLY one
-    pallas_call, vs one per busy instance on the per-instance path."""
+    """The tentpole contract: a fused bank round of a four-instance bank
+    traces to EXACTLY one pallas_call, the core round to none."""
     plan = planner.plan_throughput(32, 32, Fraction(7, 2))
     batch = 14
     fused = Bank(plan, 32, 32, backend="fused")
-    per = Bank(plan, 32, 32, backend="kernel")
+    assert len(fused.instances) == 4
     assert fused.launch_count(batch) == 1
-    assert per.launch_count(batch) == len(per.instances) == 4
     core = Bank(plan, 32, 32, backend="core")
     assert core.launch_count(batch) == 0
 
@@ -307,13 +297,19 @@ def test_fused_interval_walk_matches_windows():
 
 def test_fused_working_set_is_max_not_sum():
     """Fused instances time-share one datapath: the bank working set is
-    the largest instance footprint, not the per-instance sum."""
+    that one footprint, not the per-instance sum the core bank reports."""
+    from repro.kernels import bank_fold, mcim_fold
     plan = planner.plan_throughput(32, 32, Fraction(7, 2))
     fused = Bank(plan, 32, 32, backend="fused")
-    per = Bank(plan, 32, 32, backend="kernel")
+    core = Bank(plan, 32, 32, backend="core")
     rf = fused.report(14)
-    rp = per.report(14)
-    assert rf.working_set_bytes < rp.working_set_bytes
+    rc = core.report(14)
+    assert rf.working_set_bytes == bank_fold.vmem_bytes_per_step(2, 2, 256)
+    # three Stars (CT=1) and one FB CT=2, each its own mcim_fold datapath
+    assert rc.working_set_bytes == (
+        3 * mcim_fold.vmem_bytes_per_step(2, 2, 1, 256)
+        + mcim_fold.vmem_bytes_per_step(2, 2, 2, 256))
+    assert rf.working_set_bytes < rc.working_set_bytes
 
 
 def test_fused_refuses_mixed_signedness():
@@ -323,19 +319,6 @@ def test_fused_refuses_mixed_signedness():
                         throughput=Fraction(3, 2), area=1.0)
     with pytest.raises(ValueError, match="signedness"):
         Bank(plan, 32, 32, backend="fused")
-
-
-def test_dispatch_mul_cached_across_banks():
-    """The satellite: two Banks over the same plan share the SAME
-    multiplier callables (jax's jit cache keys on function identity, so
-    identity sharing is what stops re-tracing)."""
-    plan = planner.plan_throughput(32, 32, Fraction(7, 2))
-    b1 = Bank(plan, 32, 32, backend="kernel")
-    b2 = Bank(plan, 32, 32, backend="kernel")
-    assert all(m1 is m2 for m1, m2 in zip(b1._muls, b2._muls))
-    cfg = plan.configs[0][1]
-    assert cached_mul(cfg.arch, "kernel", cfg, 2, 2) is \
-        cached_mul(cfg.arch, "kernel", cfg, 2, 2)
 
 
 def test_auto_backend_resolves_core_on_cpu():

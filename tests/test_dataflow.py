@@ -20,7 +20,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import limbs as L
 from repro.core.mcim import MCIMConfig
+from repro.designs import registry
 from repro.kernels import bank_fold, mcim_fold
+from repro.kernels import introspect
 from repro.kernels.introspect import LaunchContract
 from repro.verify import (DataflowError, VerificationError,
                           assert_plan_dataflow, dataflow, vmem)
@@ -35,7 +37,6 @@ def _violated(violations, rule):
 def test_registry_plans_prove_clean_on_both_substrates():
     """All 13 registry design points: every implied launch verifies
     with zero violations and a positive static arithmetic intensity."""
-    from repro.designs import registry
     from repro.designs.compile import _plan_with_timing
     names = sorted(registry.names())
     assert len(names) >= 13
@@ -60,8 +61,11 @@ def test_vocabulary_clean_at_one_width():
     vocab += [MCIMConfig(arch=a, ct=ct) for a in ("fb", "ff")
               for ct in (2, 3, 12)]
     for cfg in vocab:
-        vs = dataflow.verify_plan_dataflow(32, 32, ((1, cfg),))
-        assert not vs, (cfg, [v.describe() for v in vs])
+        for substrate in ("kernel", "fused"):
+            vs = [v for rep in dataflow.analyze_plan(
+                32, 32, ((1, cfg),), substrate=substrate)
+                for v in rep.violations]
+            assert not vs, (cfg, substrate, [v.describe() for v in vs])
 
 
 def test_signed_configs_analyze_like_unsigned():
@@ -297,7 +301,7 @@ def test_vmem_budget_overflow_rejected():
 
 def test_vmem_breakdown_measures_kernel_refs():
     c = mcim_fold.launch_contract(2, 2, 2, "fb")
-    eqn = dataflow.jaxpr_walk.find_pallas_calls(c.trace().jaxpr)[0]
+    eqn = introspect.find_pallas_calls(c.trace().jaxpr)[0]
     bd = vmem.measure(eqn)
     assert bd.in_bytes > 0 and bd.out_bytes > 0
     assert bd.scratch_bytes > 0
@@ -339,15 +343,37 @@ def test_generate_gates_dataflow(monkeypatch):
     assert calls
 
 
+@pytest.mark.parametrize("name", registry.names())
+def test_plan_gate_analyzes_one_launch_per_plan(name, monkeypatch):
+    """The plan-time gate proves exactly the one fused launch the bank
+    issues for the plan, and no per-instance ``mcim_fold`` launch."""
+    from repro.designs.compile import _plan_with_timing
+    spec = registry.get(name)
+    plan, _ = _plan_with_timing(spec)
+    seen = []
+    real = dataflow.analyze_plan
+
+    def spy(*a, **kw):
+        reps = real(*a, **kw)
+        seen.extend(reps)
+        return reps
+
+    def refuse(*a, **kw):
+        raise AssertionError("the gate analyzed an mcim_fold launch")
+
+    monkeypatch.setattr(dataflow, "analyze_plan", spy)
+    monkeypatch.setattr(dataflow, "_kernel_report", refuse)
+    assert_plan_dataflow(spec.bits_a, spec.bits_b, plan.configs)
+    assert [r.name.split("[")[0] for r in seen] == ["bank_fold"]
+
+
 # ----------------------------------------------------------- roofline
 
 def test_roofline_shares_jaxpr_walker():
-    """launch.roofline's Pallas counting runs on verify.jaxpr_walk."""
-    from repro.launch import roofline
+    """The bank's launch counting and the analyzer share one walker."""
     c = bank_fold.launch_contract((MCIMConfig(arch="star", ct=1),), 2, 2)
-    assert roofline.count_pallas_launches(c.fn, *c.args) == 1
-    assert dataflow.jaxpr_walk.count_primitive(c.trace().jaxpr,
-                                               "pallas_call") == 1
+    assert introspect.count_pallas_launches(c.fn, *c.args) == 1
+    assert introspect.count_primitive(c.trace().jaxpr, "pallas_call") == 1
 
 
 def test_static_stats_for_bench_columns():

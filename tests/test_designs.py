@@ -7,10 +7,12 @@ exceeds it) and provenance (spec -> json -> spec -> generate is
 bit-exact vs the original design's mul on random operands, for every
 registered Table-VIII design point)."""
 import dataclasses
+import json
 from fractions import Fraction
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro import designs
@@ -156,10 +158,31 @@ def test_int_convenience_and_signed_mul():
     assert all(cfg.signed for _, cfg in ds.plan.configs)
 
 
-def test_signed_rejects_kernel_backend():
-    with pytest.raises(designs.DesignError):
-        designs.generate(
-            designs.DesignSpec(32, 32, 1, signed=True, backend="kernel"))
+def test_spec_refuses_kernel_backend():
+    """``backend="kernel"`` is no choice: the constructor and the JSON
+    both raise DesignError, naming the choices, signed or not."""
+    for signed in (False, True):
+        with pytest.raises(designs.DesignError, match="'auto', 'core', "
+                                                      "'fused'"):
+            designs.DesignSpec(32, 32, 1, signed=signed, backend="kernel")
+        doc = designs.DesignSpec(32, 32, 1, signed=signed).to_dict()
+        doc["backend"] = "kernel"
+        with pytest.raises(designs.DesignError, match="'kernel'"):
+            designs.DesignSpec.from_json(json.dumps(doc))
+
+
+@pytest.mark.parametrize("name", designs.names())
+def test_auto_backend_resolves_fused_on_tpu(name, monkeypatch):
+    """On a TPU, ``auto`` is the fused megakernel for every registry
+    design, signed or not."""
+    from repro.kernels import runtime
+    runtime.interpret_mode()          # cache the CPU's answer first
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = designs.get(name)
+    assert spec.backend == "auto"
+    for signed in (False, True):
+        design = designs.generate(dataclasses.replace(spec, signed=signed))
+        assert design.bank.backend == "fused"
 
 
 def test_scheduler_flows_from_spec_to_reports():

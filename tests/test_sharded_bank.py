@@ -1,10 +1,8 @@
 """Sharded multi-bank execution on a 2-device placeholder mesh
 (subprocess, like test_distributed_features): ``sharded_execute`` must
 be bit-exact vs the Python-bigint oracle and vs the single-bank engine,
-for core and kernel backends and both batch-available schedulers.  Also
-pins the backend-registry acceptance: the kernel capability routes every
-planner arch (star, fb, ff, karatsuba CT=3) through Pallas with no core
-fallback.  On four devices, a design generated with ``replicas=4``
+for the core and fused backends and both batch-available schedulers.
+On four devices, a design generated with ``replicas=4``
 multiplies through ``CompiledDesign.mul`` as the Python integers do, on
 the fused and the core backend."""
 import os
@@ -14,7 +12,6 @@ import sys
 import pytest
 
 from repro.core import planner
-from repro.core.bank import backends as B
 
 SCRIPT = r"""
 import os
@@ -42,7 +39,7 @@ for plan, bits in cases:
     b = jnp.asarray(L.random_limbs(rng, (28,), bits))
     expect = [L.from_limbs(np.asarray(x)) * L.from_limbs(np.asarray(y))
               for x, y in zip(a, b)]
-    for backend in ("core", "kernel"):
+    for backend in ("core", "fused"):
         for sched in ("round_robin", "greedy"):
             out = bank.sharded_execute(plan, a, b, mesh, "data",
                                        backend=backend, scheduler=sched)
@@ -144,37 +141,11 @@ def test_replicated_design_matches_integers_on_four_devices(backend):
     assert "ALLOK" in out.stdout, out.stdout
 
 
-# ---------------------------------------------------- backend registry
-
-def test_kernel_capability_has_no_core_fallback():
-    """Every planner arch resolves to a Pallas big_mul partial under the
-    kernel capability -- the PR-2 Karatsuba core fallback is gone."""
-    from repro.kernels.mcim_fold.ops import big_mul
-    from repro.core.mcim import MCIMConfig
-    for arch, cfg in [
-            ("star", MCIMConfig(arch="star", ct=1)),
-            ("fb", MCIMConfig(arch="fb", ct=2)),
-            ("ff", MCIMConfig(arch="ff", ct=2)),
-            ("karatsuba", MCIMConfig(arch="karatsuba", ct=3))]:
-        be = B.get_backend(arch, "kernel")
-        mul = be.make_mul(cfg, 8, 8)
-        assert getattr(mul, "func", None) is big_mul, (arch, mul)
-    kw = B.get_backend("karatsuba", "kernel").make_mul(
-        MCIMConfig(arch="karatsuba", ct=3), 8, 8).keywords
-    assert kw == {"ct": 3, "schedule": "karatsuba"}
-
-
-def test_every_planner_arch_has_both_capabilities():
-    keys = B.registered_backends()
-    for arch in ("star", "fb", "ff", "karatsuba"):
-        for cap in B.CAPABILITIES:
-            assert (arch, cap) in keys
-    with pytest.raises(ValueError):
-        B.get_backend("star", "fpga")
-
+# ------------------------------------------------------------ backends
 
 def test_unknown_backend_capability_rejected_by_bank():
     from repro.core.bank import Bank
     plan = planner.plan_throughput(32, 32, 1)
-    with pytest.raises(ValueError):
-        Bank(plan, 32, 32, backend="fpga")
+    for backend in ("fpga", "kernel"):
+        with pytest.raises(ValueError, match="backend must be one of"):
+            Bank(plan, 32, 32, backend=backend)
